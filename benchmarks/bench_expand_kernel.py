@@ -23,7 +23,6 @@ from __future__ import annotations
 import time
 
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import CacheConfig, bowtie_query, choose_plan, cycle_query, engine
 from repro.core.cached_frontier import JaxCachedTrieJoin
@@ -60,7 +59,7 @@ def micro_sweep() -> None:
     q = cycle_query(4)
     td, order = choose_plan(q, db.stats())
     interpret = jax.default_backend() not in ("tpu", "gpu")
-    with enable_x64():
+    with jax.enable_x64(True):
         for cap in CAPS:
             eng = JaxCachedTrieJoin(q, td, order, db, capacity=cap)
             a0 = eng.expand_kernel_args(0)

@@ -25,7 +25,6 @@ import time
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (CacheConfig, SyncCounter, bowtie_query, choose_plan,
                         engine)
@@ -88,7 +87,7 @@ def _time_call(fn, args, reps=5):
 
 def micro_sweep() -> None:
     interpret = jax.default_backend() not in ("tpu", "gpu")
-    with enable_x64():
+    with jax.enable_x64(True):
         for cap in CAPS:
             args = _fold_inputs(cap)
             fns = {"xla": fxla.build(d0=D0, d1=D1, with_replay=True,

@@ -44,6 +44,9 @@ def main() -> None:
                     help="write structured records to PATH "
                          "(default BENCH_<date>.json)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     mods = MODULES if not args.only else [
         m for m in MODULES if any(s in m for s in args.only.split(","))]
     print("name,us_per_call,derived")

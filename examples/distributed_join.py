@@ -20,9 +20,11 @@ import time                             # noqa: E402
 from repro.core import choose_plan, cycle_query, lftj_count  # noqa: E402
 from repro.core.distributed import make_distributed_count    # noqa: E402
 from repro.data.graphs import dataset   # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main() -> None:
+    enable_compile_cache()
     db = dataset(args.dataset)
     q = cycle_query(4)
     td, order = choose_plan(q, db.stats())

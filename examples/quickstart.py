@@ -7,9 +7,11 @@ import numpy as np
 from repro.core import (CachePolicy, Counters, choose_plan, clftj_count,
                         cycle_query, path_query, graph_db, lftj_count, engine)
 from repro.data.graphs import dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     # a skewed graph (ego-Twitter-like) and the paper's flagship 5-cycle
     db = dataset("wiki-vote-like")
     q = path_query(4)

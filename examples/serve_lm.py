@@ -10,11 +10,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.train.serve_step import greedy_generate
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b-smoke")
     ap.add_argument("--batch", type=int, default=4)

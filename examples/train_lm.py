@@ -9,6 +9,7 @@ import shutil
 
 from repro.configs.base import ArchConfig
 from repro.data.tokens import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.optim.adamw import OptConfig
 from repro.train.loop import LoopConfig, train
@@ -29,6 +30,7 @@ PRESETS = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="ci", choices=list(PRESETS))
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")

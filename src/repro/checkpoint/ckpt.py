@@ -21,13 +21,12 @@ import numpy as np
 
 import jax
 
-from ..compat import tree_flatten_with_path
 
 _SEP = "||"
 
 
 def _flatten(tree) -> Tuple[Dict[str, np.ndarray], Any]:
-    flat, treedef = tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     out = {}
     for path, leaf in flat:
         key = _SEP.join(_path_str(p) for p in path)
@@ -116,7 +115,7 @@ class CheckpointManager:
         data = np.load(os.path.join(path, "arrays.npz"))
         with open(os.path.join(path, "meta.json")) as f:
             meta = json.load(f)
-        flat, treedef = tree_flatten_with_path(like)
+        flat, treedef = jax.tree.flatten_with_path(like)
         leaves = []
         shard_flat = jax.tree.leaves(shardings) if shardings is not None \
             else [None] * len(flat)

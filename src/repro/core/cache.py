@@ -54,7 +54,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from .hostsync import device_get
 
@@ -655,7 +654,7 @@ class DeviceCache:
             return "rejected"
         # adoption must run under x64 or the int64 key/count planes are
         # silently truncated to int32 (packed adhesion keys would corrupt)
-        with enable_x64():
+        with jax.enable_x64(True):
             self.keys = jnp.asarray(keys)
             self.vals = jnp.asarray(vals)
             self.used = jnp.asarray(used)
@@ -693,7 +692,7 @@ class DeviceCache:
                 # overwrite rows a key still points at (stale splice)
                 if (off < 0).any() or ((off + ln) > bump).any():
                     raise ValueError("resident block outside slab epoch")
-            with enable_x64():
+            with jax.enable_x64(True):
                 self.pay_off = jnp.asarray(pay_off)
                 self.pay_len = jnp.asarray(pay_len)
                 self.slab = None if slab is None else jnp.asarray(slab)
@@ -782,7 +781,7 @@ class CacheManager:
         (``"ok"``/``"flushed"``/``"rejected"`` — see
         :meth:`DeviceCache.import_state`)."""
         out: Dict[int, str] = {}
-        with enable_x64():  # table creation allocates int64 planes
+        with jax.enable_x64(True):  # table creation allocates int64 planes
             for v, st in states.items():
                 v = int(v)
                 if not self.node_enabled(v):

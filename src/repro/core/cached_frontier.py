@@ -37,8 +37,8 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from .cache import CacheConfig, CacheManager
 from .cq import CQ
@@ -160,7 +160,7 @@ class JaxCachedTrieJoin(JaxTrieJoin):
 
     # -----------------------------------------------------------------
     def count(self) -> int:
-        with enable_x64():
+        with jax.enable_x64(True):
             ex = ScheduleExecutor(self, mode="count")
             self.last_executor = ex  # op_runs / sync diagnostics
             total = ex.count()
@@ -180,7 +180,7 @@ class JaxCachedTrieJoin(JaxTrieJoin):
         block's ``pay_len`` result rows).
         Count-only tables cannot replay tuples and are bypassed
         (optionality — the cache is never required for correctness)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             ex = ScheduleExecutor(self, mode="evaluate")
             self.last_executor = ex
             yield from ex.evaluate()
@@ -194,7 +194,7 @@ class JaxCachedTrieJoin(JaxTrieJoin):
         instead of draining at pass end.  All tier-2 behavior (payload
         probe/splice/store) is unchanged: streaming only moves the output
         data plane."""
-        with enable_x64():
+        with jax.enable_x64(True):
             ex = ScheduleExecutor(self, mode="evaluate")
             self.last_executor = ex
             try:

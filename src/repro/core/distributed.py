@@ -28,8 +28,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .cache import CacheConfig
@@ -38,7 +36,7 @@ from .cq import CQ
 from .db import Database
 from .frontier import Frontier
 from .hostsync import device_get
-from .schedule import FOLD_CHILD, execute_static
+from .schedule import EXPAND, FOLD_CHILD, execute_static
 from .td import TreeDecomposition
 
 
@@ -84,6 +82,24 @@ class StaticCLFTJ(JaxCachedTrieJoin):
                 tables[op.node] = base
         return tables
 
+    def resolve_kernels(self, mode: str = "count") -> None:
+        """Resolve every registry kernel :func:`execute_static` traces in
+        ``mode``, before any trace: selection may compile and time
+        kernels, which it cannot do inside a ``shard_map`` trace.  Folds
+        are resolved whether or not their exits end up sorted (an unsorted
+        fold takes the XLA chain without asking the registry)."""
+        cfg = self.cache_config
+        payloads = cfg.initial_slots() > 0 and cfg.cache_payloads
+        with jax.enable_x64(True):
+            for op in self.schedule.ops:
+                if op.kind == EXPAND:
+                    self._expand_fn(op.d)
+                elif op.kind == FOLD_CHILD and mode == "evaluate":
+                    self._fold_fn(op.sub_first, op.sub_last, True,
+                                  op.probe and payloads)
+            if mode == "evaluate":
+                self._emit_fn()
+
     def count_fn(self):
         """Returns a pure fn(frontier0) -> (count, overflow)."""
         cfg = self.cache_config
@@ -115,7 +131,7 @@ class StaticCLFTJ(JaxCachedTrieJoin):
         ``tier2_replay_hits``, and the updated functional tables to pass
         back in for a warm pass (recurring adhesion keys then splice from
         the slab instead of re-expanding)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             if tables is None:
                 tables = self.make_tables("evaluate")
             F0 = self.initial_frontier()
@@ -181,25 +197,26 @@ def make_distributed_count(q: CQ, td: TreeDecomposition,
     [i·R/D, (i+1)·R/D); relations are replicated (closure constants); the
     final count is a psum over the mesh axes — the single collective.
     ``expand_kernel``/``fold_kernel``/``emit_kernel`` are resolved per
-    spec at trace time (the registry choices are baked into the unrolled
-    schedule, identically per shard).
+    spec before the ``shard_map`` trace (the registry choices are baked
+    into the unrolled schedule, identically per shard).
     """
     cache = _resolve_cache_config(cache, None, default_slots=1 << 15)
     eng = StaticCLFTJ(q, td, order, db, capacity=capacity, cache=cache,
                       expand_kernel=expand_kernel, fold_kernel=fold_kernel,
                       emit_kernel=emit_kernel)
     part = _GuardPartition(eng, mesh, axes)
+    eng.resolve_kernels("count")
     count_fn = eng.count_fn()
 
     def per_shard():
-        with enable_x64():
+        with jax.enable_x64(True):
             total, ov = count_fn(part.shard_frontier())
             total = jax.lax.psum(total, part.all_axes)
             ov = jax.lax.psum(ov.astype(jnp.int32), part.all_axes)
             return total, ov
 
-    fn = shard_map(per_shard, mesh=mesh, in_specs=(),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(per_shard, mesh=mesh, in_specs=(),
+                       out_specs=(P(), P()), check_vma=False)
     return _X64Jit(fn), eng
 
 
@@ -236,14 +253,15 @@ def make_distributed_evaluate(q: CQ, td: TreeDecomposition,
                       emit_kernel=emit_kernel)
     part = _GuardPartition(eng, mesh, axes)
     d_total = part.d_total
+    eng.resolve_kernels("evaluate")
     eval_fn = eng.evaluate_fn()
     spec = P(part.all_axes)
-    with enable_x64():
+    with jax.enable_x64(True):
         template = eng.make_tables("evaluate")
     table_specs = jax.tree.map(lambda _: spec, template)
 
     def init_tables():
-        with enable_x64():
+        with jax.enable_x64(True):
             # stack the spec template itself — building a second full
             # table set (slab arenas included) just to throw it away
             # would double the allocation per factory call
@@ -251,7 +269,7 @@ def make_distributed_evaluate(q: CQ, td: TreeDecomposition,
                 lambda x: jnp.repeat(x[None], d_total, axis=0), template)
 
     def per_shard(tables):
-        with enable_x64():
+        with jax.enable_x64(True):
             local = jax.tree.map(lambda x: x[0], tables)
             assign, valid, total, ov, hits, local = eval_fn(
                 part.shard_frontier(), local)
@@ -261,10 +279,10 @@ def make_distributed_evaluate(q: CQ, td: TreeDecomposition,
             return (assign[None], valid[None], total, ov, hits,
                     jax.tree.map(lambda x: x[None], local))
 
-    fn = _X64Jit(shard_map(
+    fn = _X64Jit(jax.shard_map(
         per_shard, mesh=mesh, in_specs=(table_specs,),
         out_specs=(spec, spec, P(), P(), P(), table_specs),
-        check_rep=False))
+        check_vma=False))
 
     def run(tables: Optional[Dict[int, tuple]] = None):
         if tables is None:
@@ -299,9 +317,9 @@ class _X64Jit:
         self._jit = jax.jit(fn)
 
     def __call__(self, *args, **kwargs):
-        with enable_x64():
+        with jax.enable_x64(True):
             return self._jit(*args, **kwargs)
 
     def lower(self, *args, **kwargs):
-        with enable_x64():
+        with jax.enable_x64(True):
             return self._jit.lower(*args, **kwargs)

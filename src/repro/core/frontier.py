@@ -42,7 +42,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from ..kernels import registry as kernels
 from .cq import CQ
@@ -291,80 +290,75 @@ class JaxTrieJoin:
         return g_ai, self.levels[g_ai][g_lvl].runstarts_np, self.sizes[g_ai]
 
     def split_chunk_host(self, host: Dict[str, np.ndarray], d: int,
-                         counts: np.ndarray) -> List[Frontier]:
+                         counts: np.ndarray) -> Iterator[Frontier]:
         """Split a chunk whose expansion would overflow capacity.
 
         ``host`` is the chunk already fetched to host (one batched sync by
-        the executor).  Rows are greedily packed into pieces whose total
-        candidate count fits; a single oversized row is split by guard
-        *run ranges*, so each piece enumerates a disjoint slice of its
-        candidate values.
+        the executor).  A row whose candidates fit stays whole; an
+        oversized row is split by guard *run ranges* of at most C runs, so
+        each piece enumerates a disjoint slice of its candidate values.
+        The resulting entries are greedily packed, in order, into pieces
+        of at most C rows and at most C candidates, yielded one at a time
+        (each piece reaches the device only when the caller takes it).
+        Vectorized: a few array passes per chunk, no per-row Python work.
         """
         C = self.capacity
         g_ai, rs, n_rows_g = self.expand_plan(d)
-        rows: List[Dict[str, np.ndarray]] = []
-        for i in np.flatnonzero(host["valid"]):
-            c = int(counts[i])
-            if c <= C:
-                rows.append({k: v[i] for k, v in host.items()})
-                continue
-            # oversized: split the guard run range
-            lo_i, hi_i = int(host["lo"][i, g_ai]), int(host["hi"][i, g_ai])
-            r0 = int(np.searchsorted(rs, lo_i, side="left"))
-            r1 = int(np.searchsorted(rs, hi_i, side="left"))
-            for a in range(r0, r1, C):
-                b = min(a + C, r1)
-                piece = {k: v[i].copy() for k, v in host.items()}
-                piece["lo"] = piece["lo"].copy()
-                piece["hi"] = piece["hi"].copy()
-                piece["lo"][g_ai] = rs[a]
-                piece["hi"][g_ai] = rs[b] if b < len(rs) else n_rows_g
-                rows.append(piece)
-        # greedy pack rows into pieces
-        pieces: List[Frontier] = []
-        cur: List[Dict[str, np.ndarray]] = []
-        cur_count = 0
+        idx = np.flatnonzero(host["valid"])
+        lo_g = host["lo"][idx, g_ai].astype(np.int64)
+        hi_g = host["hi"][idx, g_ai].astype(np.int64)
+        r0 = np.searchsorted(rs, lo_g, side="left")
+        r1 = np.searchsorted(rs, hi_g, side="left")
+        big = counts[idx] > C
+        # entries: one per whole row, ceil(runs / C) per oversized row
+        n_seg = np.where(big, -(-(r1 - r0) // C), 1)
+        row = np.repeat(idx, n_seg)
+        seg = np.arange(row.size) - np.repeat(np.cumsum(n_seg) - n_seg,
+                                              n_seg)
+        split = np.repeat(big, n_seg)
+        a = np.repeat(r0, n_seg) + seg * C
+        b = np.minimum(a + C, np.repeat(r1, n_seg))
+        ext = np.append(rs, n_rows_g).astype(np.int64)
+        e_lo = np.where(split, ext[np.minimum(a, len(rs))],
+                        np.repeat(lo_g, n_seg))
+        e_hi = np.where(split, ext[np.minimum(b, len(rs))],
+                        np.repeat(hi_g, n_seg))
+        cnt = np.where(split, b - a, np.repeat(r1 - r0, n_seg))
+        # greedy pack: a piece grows while its rows <= C and count <= C
+        cum = np.concatenate([[0], np.cumsum(cnt)])
+        p = 0
+        while p < row.size:
+            q = int(np.searchsorted(cum, cum[p] + C, side="right")) - 1
+            q = max(p + 1, min(q, p + C, row.size))
+            fields = {k: v[row[p:q]] for k, v in host.items()}
+            fields["lo"][:, g_ai] = e_lo[p:q]
+            fields["hi"][:, g_ai] = e_hi[p:q]
+            yield self._pack_rows(fields, q - p)
+            p = q
 
-        def flush():
-            nonlocal cur, cur_count
-            if not cur:
-                return
-            pieces.append(self._pack_rows(cur))
-            cur, cur_count = [], 0
-
-        for r in rows:
-            lo_r, hi_r = int(r["lo"][g_ai]), int(r["hi"][g_ai])
-            c = int(np.searchsorted(rs, hi_r) - np.searchsorted(rs, lo_r))
-            if cur and (cur_count + c > C or len(cur) == C):
-                flush()
-            cur.append(r)
-            cur_count += c
-        flush()
-        return pieces
-
-    def _pack_rows(self, rows: List[Dict[str, np.ndarray]]) -> Frontier:
+    def _pack_rows(self, fields: Dict[str, np.ndarray], n: int) -> Frontier:
+        """A chunk holding ``n`` rows (``fields``' leading axis), padded
+        with invalid zero rows to capacity."""
         C = self.capacity
         out = {}
         for k in Frontier._fields:
-            proto = rows[0][k]
-            arr = np.zeros((C,) + proto.shape, dtype=proto.dtype)
-            for i, r in enumerate(rows):
-                arr[i] = r[k]
+            v = fields[k]
+            arr = np.zeros((C,) + v.shape[1:], dtype=v.dtype)
+            arr[:n] = v
             out[k] = jnp.asarray(arr)
-        out["valid"] = jnp.asarray(
-            np.arange(C) < len(rows)) & out["valid"].astype(bool)
+        out["valid"] = jnp.asarray(np.arange(C) < n)
         return Frontier(**out)
 
     # ------------------------------------------------------------------
     def count(self) -> int:
-        with enable_x64():
+        with jax.enable_x64(True):
             ex = ScheduleExecutor(self, mode="count")
             self.last_executor = ex  # op_runs / sync diagnostics
             return ex.count()
 
     def evaluate(self) -> Iterator[np.ndarray]:
         """Yields (k, n) blocks of result assignments (order columns)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             ex = ScheduleExecutor(self, mode="evaluate")
             self.last_executor = ex
             yield from ex.evaluate()
@@ -374,7 +368,7 @@ class JaxTrieJoin:
         the same order, with each block's device→host copy issued
         asynchronously as the block is produced (bounded by
         ``emit_in_flight``; DESIGN.md §2.8)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             ex = ScheduleExecutor(self, mode="evaluate")
             self.last_executor = ex
             yield from ex.evaluate_stream()

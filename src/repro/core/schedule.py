@@ -40,6 +40,7 @@ op flags, not engine-subclass overrides.
 from __future__ import annotations
 
 import functools
+import itertools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -48,12 +49,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..kernels.fold.xla import (merge_compact as _merge_compact,
+from ..kernels.expand.xla import valid_first
+from ..kernels.fold.xla import (_exit_order,
+                                merge_compact as _merge_compact,
                                 replay_step as _replay_step,
                                 splice_step as _splice_step)
 from .hostsync import AsyncFetchQueue, device_get, device_get_async
 
 MAX_KEY_BITS = 21  # packed adhesion keys: values must fit in 21 bits
+# EXPAND launches between admissions (each one blocking sync) of a level
+ADMIT_BATCH = 128
 
 # ---------------------------------------------------------------------------
 # The IR
@@ -246,6 +251,27 @@ def _apply_counts(F, hit, hvals, rep_of_row, cnt):
     return F._replace(factor=factor, valid=F.valid & (factor > 0))
 
 
+@jax.jit
+def _chunk_stats(F) -> jnp.ndarray:
+    """``[valid rows, min orig, max orig]`` of a valid-prefix chunk (the
+    orig bounds over valid rows; empty chunks give ``[0, max, -1]``)."""
+    big = jnp.iinfo(jnp.int32).max
+    return jnp.stack([jnp.sum(F.valid.astype(jnp.int32)),
+                      jnp.min(jnp.where(F.valid, F.orig, big)),
+                      jnp.max(jnp.where(F.valid, F.orig, -1))])
+
+
+@jax.jit
+def _merge_chunks(A, B):
+    """A's valid rows then B's, in order, packed into one chunk (the
+    caller guarantees they fit); either may have holes in ``valid``."""
+    C = A.valid.shape[0]
+    cat = type(A)(*(jnp.concatenate([a, b]) for a, b in zip(A, B)))
+    perm, k = valid_first(cat.valid)
+    out = type(A)(*(x[perm[:C]] for x in cat))
+    return out._replace(valid=jnp.arange(C) < k)
+
+
 @functools.partial(jax.jit, static_argnames=("n_slots",))
 def _segment_counts(exit_F, n_slots: int) -> jnp.ndarray:
     contrib = jnp.where(exit_F.valid, exit_F.factor, 0)
@@ -253,13 +279,15 @@ def _segment_counts(exit_F, n_slots: int) -> jnp.ndarray:
         jnp.clip(exit_F.orig, 0, n_slots - 1)].add(contrib)
 
 
-@functools.partial(jax.jit, static_argnames=("d0", "d1"))
-def _store_blocks(slab, E, poff, admit, *, d0: int, d1: int):
+@functools.partial(jax.jit, static_argnames=("d0", "d1", "sorted_exits"))
+def _store_blocks(slab, E, poff, admit, *, d0: int, d1: int,
+                  sorted_exits: bool = True):
     """Write one exit chunk's per-representative row blocks into the slab
     arena (tier-2 payload insert, evaluation mode).
 
-    Exit rows are sorted by representative id exactly as in
-    :func:`_replay_step`; rep *r*'s rows land contiguously at ``poff[r]``.
+    Exit rows are ordered by representative id exactly as in
+    :func:`_replay_step` (``sorted_exits`` as there); rep *r*'s rows land
+    contiguously at ``poff[r]``.
     Refused or invalid rows are routed to the arena's scratch row (the
     last one) — a masked ``.set`` must never target a live slot, or a
     "keep old value" no-op could land after a real write and clobber it.
@@ -268,8 +296,7 @@ def _store_blocks(slab, E, poff, admit, *, d0: int, d1: int):
     R = slab.shape[0] - 1  # last row = scratch
     ecnt = jnp.zeros((C,), jnp.int32).at[
         jnp.clip(E.orig, 0, C - 1)].add(E.valid.astype(jnp.int32))
-    ekey = jnp.where(E.valid, jnp.clip(E.orig, 0, C - 1), jnp.int32(C))
-    eorder = jnp.argsort(ekey, stable=True)
+    eorder = _exit_order(E, sorted_exits)
     estart = jnp.cumsum(ecnt) - ecnt
     j = jnp.arange(C, dtype=jnp.int32)
     rep = jnp.clip(E.orig[eorder], 0, C - 1)
@@ -601,18 +628,28 @@ class ScheduleExecutor:
                 to_run.append(F)
             else:
                 oversized.append((F, counts))
+        pieces: Iterator[Any] = iter(to_run)
         if oversized:
             # one batched fetch for every chunk that needs morsel splitting
             hosts = device_get([F._asdict() for F, _ in oversized],
                                "expand-split")
-            for (_, counts), host in zip(oversized, hosts):
-                host = {k: np.asarray(v) for k, v in host.items()}
-                to_run.extend(eng.split_chunk_host(host, d, counts))
+            pieces = itertools.chain(pieces, *(
+                eng.split_chunk_host({k: np.asarray(v)
+                                      for k, v in host.items()}, d, counts)
+                for (_, counts), host in zip(oversized, hosts)))
         fn = eng._expand_fn(d)
         path = getattr(eng, "expand_paths", {}).get(d, "xla")
-        self.expand_path_runs[path] = (
-            self.expand_path_runs.get(path, 0) + len(to_run))
-        return self._admit([fn(F)[0] for F in to_run], "expand-admit")
+        # pieces go to the device lazily and their outputs are admitted
+        # (coalesced) every ADMIT_BATCH launches, so a wide level holds
+        # at most one batch of sparse outputs at a time
+        kept: List[Any] = []
+        while True:
+            batch = [fn(F)[0] for F in itertools.islice(pieces, ADMIT_BATCH)]
+            if not batch:
+                return kept
+            self.expand_path_runs[path] = (
+                self.expand_path_runs.get(path, 0) + len(batch))
+            kept[-1:] = self._admit(kept[-1:] + batch, "expand-admit")
 
     # -- ENTER_CHILD (one parent chunk) --------------------------------
     def _enter_one(self, F, op: Op) -> Tuple[_Frame, Any]:
@@ -870,11 +907,40 @@ class ScheduleExecutor:
 
     # -- shared --------------------------------------------------------
     def _admit(self, out, label: str):
-        """Drop empty chunks with ONE batched host sync for the whole op."""
+        """Drop empty chunks and coalesce sparse neighbours, with ONE
+        batched host sync for the whole op.
+
+        In count mode, consecutive chunks merge while their valid rows fit
+        one chunk and ``orig`` stays nondecreasing across the seam (the
+        sorted-exits invariant), so the row sequence is unchanged.
+        Without this an op over a wide level keeps every sparse output
+        chunk alive: the level's device memory then scales with the
+        candidate count instead of the surviving rows.  Evaluation keeps
+        chunk boundaries: they decide which tier-2 probes a payload insert
+        precedes, hence the order of replayed and spliced rows, and a
+        streamed pass must emit the rows of a one-shot pass in order."""
         if not out:
             return []
-        keep = device_get(jnp.stack([F.valid.any() for F in out]), label)
-        return [F for F, k in zip(out, np.asarray(keep)) if k]
+        C = self.engine.capacity
+        coalesce = self.mode == "count"
+        stats = np.asarray(device_get(
+            jnp.stack([_chunk_stats(F) for F in out]), label))
+        kept: List[Any] = []
+        acc, acc_n, acc_hi = None, 0, 0
+        for F, (n, lo, hi) in zip(out, stats.tolist()):
+            if n == 0:
+                continue
+            if (coalesce and acc is not None and acc_n + n <= C
+                    and acc_hi <= lo):
+                acc = _merge_chunks(acc, F)
+                acc_n, acc_hi = acc_n + n, hi
+                continue
+            if acc is not None:
+                kept.append(acc)
+            acc, acc_n, acc_hi = F, n, hi
+        if acc is not None:
+            kept.append(acc)
+        return kept
 
 
 def _pack_parent_morsels(pcnt: np.ndarray, cap: int) -> List[np.ndarray]:
@@ -1006,19 +1072,20 @@ def execute_static(schedule: Schedule, engine, F0, tables: Dict[int, tuple],
             (P, keys, hit, hvals, rep_of_row, first_idx, n_reps, active,
              use_t2, poff, plen, parent_sorted) = stack.pop()
             if mode == "evaluate":
-                E = F
+                E, e_sorted = F, sorted_now
                 d0, d1 = op.sub_first, op.sub_last
                 # one registry-dispatched FOLD step: replay the miss
                 # representatives' exits through orig and (payload
                 # tables) splice + merge the hit rows' cached blocks.
                 # The fused kernel needs E sorted; an unsorted exit
                 # (nested merged fold) routes to the XLA chain directly.
-                if sorted_now:
+                if e_sorted:
                     ffn = engine._fold_fn(d0, d1, True, use_t2)
                 else:
                     from ..kernels.fold import xla as _fxla
                     ffn = _fxla.build(d0=d0, d1=d1, with_replay=True,
-                                      with_splice=use_t2)
+                                      with_splice=use_t2,
+                                      sorted_exits=False)
                 if use_t2:
                     (tk, tv, tu, ts, tc, tpoff, tplen, slab,
                      bump) = tables[op.node]
@@ -1058,7 +1125,8 @@ def execute_static(schedule: Schedule, engine, F0, tables: Dict[int, tuple],
                         bump, tplen, ecnt, eligible,
                         cap=int(cfg.payload_rows))
                     slab = _store_blocks(slab, E, offs, admit,
-                                         d0=d0, d1=d1)
+                                         d0=d0, d1=d1,
+                                         sorted_exits=e_sorted)
                     tick += 1
                     lens = ecnt.astype(jnp.int64)
                     out = cache_insert(

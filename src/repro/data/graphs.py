@@ -8,6 +8,8 @@ SNAP/IMDB are not available offline; the paper's performance story rests on
   * ``zipf_graph``      — one edge table, Zipf-distributed endpoint
     popularity (hot vertices make adhesion keys recur — the conformance
     zoo's and the kernel benchmarks' shared skew source),
+  * ``zipf_digraph``    — the same popularity with an exact count of
+    distinct directed edges (sized stand-ins for a named SNAP graph),
   * ``zipf_bipartite``  — two-table person/movie workload with separately
     tunable per-attribute skew (IMDB cast_info analogue, Fig 13/14).
 
@@ -53,6 +55,22 @@ def zipf_graph(nv: int, ne: int, a: float, seed: int = 0) -> np.ndarray:
     p /= p.sum()
     return np.stack([rng.choice(nv, size=ne, p=p),
                      rng.choice(nv, size=ne, p=p)], axis=1).astype(np.int64)
+
+
+def zipf_digraph(nv: int, ne: int, a: float, seed: int = 0) -> np.ndarray:
+    """Exactly ``ne`` distinct directed edges without self loops, with
+    Zipf(``a``)-distributed endpoint popularity (vertex 0 is the hottest).
+    :func:`zipf_graph` draws with repeats; this keeps drawing until ``ne``
+    distinct edges exist and keeps them in first-drawn order."""
+    e = np.zeros((0, 2), np.int64)
+    draw = seed
+    while len(e) < ne:
+        e = np.concatenate([e, zipf_graph(nv, ne, a, seed=draw)])
+        e = e[e[:, 0] != e[:, 1]]
+        _, first = np.unique(e[:, 0] * nv + e[:, 1], return_index=True)
+        e = e[np.sort(first)]
+        draw += 1
+    return e[:ne]
 
 
 def zipf_bipartite(n_left: int, n_right: int, m: int, a_left: float,

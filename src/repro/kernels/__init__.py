@@ -7,3 +7,11 @@
 #   emit/      — fused valid-row EMIT pack (Pallas | XLA chain)
 #   leapfrog/  — batched bounded lower/upper bound (Pallas dense count)
 #   flash_attention/ — LM-substrate attention (own ops.py facade)
+
+
+def interpret_default() -> bool:
+    """Whether a Pallas kernel runs in the interpreter when its caller does
+    not say: only where the backend has no Pallas compiler (CPU)."""
+    import jax
+
+    return jax.default_backend() not in ("tpu", "gpu")

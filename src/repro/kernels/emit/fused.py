@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import interpret_default
+
 from ..fold.fused import _search
 
 __all__ = ["FusedEmitConfig", "build"]
@@ -41,7 +43,7 @@ class FusedEmitConfig:
     def resolve_interpret(self) -> bool:
         if self.interpret is not None:
             return self.interpret
-        return jax.default_backend() not in ("tpu", "gpu")
+        return interpret_default()
 
 
 def _make_kernel(*, C: int, block_q: int):

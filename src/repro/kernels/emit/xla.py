@@ -11,19 +11,19 @@ the whole chunk and masking on the host.  Rows past ``k`` are garbage
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
+
+from ..expand.xla import valid_first
 
 __all__ = ["build"]
 
 
 def build():
     """EMIT pack under the registry contract (module docstring): the
-    always-available XLA composition (stable argsort + gather)."""
+    always-available XLA composition (valid-first order + gather)."""
 
     @jax.jit
     def fn(assign, valid):
-        perm = jnp.argsort(jnp.logical_not(valid), stable=True)
-        k = jnp.sum(valid.astype(jnp.int32))
+        perm, k = valid_first(valid)
         return assign[perm], k
 
     return fn

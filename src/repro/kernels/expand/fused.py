@@ -36,8 +36,9 @@ with ``expand_kernel="pallas"`` forced (bit-exact against the XLA chain
 on the valid prefix; invalid tail rows are garbage in both paths, only
 their ``valid=False`` is contractual).  Outputs match the XLA chain's
 compaction exactly: same survivor order (both are stable), same
-``needed``.  The registry falls back to the XLA chain if this kernel
-fails to build on a backend.
+``needed``.  Where the backend's compiler refuses this kernel, the
+registry's ``"auto"`` takes the XLA chain and a forced ``"pallas"``
+raises.
 """
 from __future__ import annotations
 
@@ -49,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import interpret_default
 
 __all__ = ["FusedExpandConfig", "build"]
 
@@ -72,7 +75,7 @@ class FusedExpandConfig:
     def resolve_interpret(self) -> bool:
         if self.interpret is not None:
             return self.interpret
-        return jax.default_backend() not in ("tpu", "gpu")
+        return interpret_default()
 
 
 def _search(col, values, lo, hi, *, strict: bool):

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import interpret_default
+
 __all__ = ["FusedFoldConfig", "build"]
 
 DEFAULT_BLOCK_Q = 1024
@@ -77,7 +79,7 @@ class FusedFoldConfig:
     def resolve_interpret(self) -> bool:
         if self.interpret is not None:
             return self.interpret
-        return jax.default_backend() not in ("tpu", "gpu")
+        return interpret_default()
 
 
 def _search(col, values, lo, hi, *, strict: bool):

@@ -29,11 +29,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..expand.xla import compact
+
 __all__ = ["replay_step", "splice_step", "merge_compact", "build"]
 
 
-@functools.partial(jax.jit, static_argnames=("d0", "d1"))
-def replay_step(P, active, rep_of_row, E, *, d0: int, d1: int):
+@functools.partial(jax.jit, static_argnames=("d0", "d1", "sorted_exits"))
+def replay_step(P, active, rep_of_row, E, *, d0: int, d1: int,
+                sorted_exits: bool = True):
     """Scatter one subtree exit chunk back through ``orig`` (evaluate mode).
 
     For every active parent row *i* (representative ``rep_of_row[i]``) and
@@ -42,13 +45,16 @@ def replay_step(P, active, rep_of_row, E, *, d0: int, d1: int):
     ``[d0, d1]`` replaced by the exit row's — the factorized intermediate
     of paper §3.4, re-expanded.  Caller guarantees the total pair count
     fits the chunk capacity (``active`` is a pre-packed morsel mask).
+    ``sorted_exits`` promises ``E`` is valid-prefix compacted with
+    nondecreasing ``orig`` (the executors' sorted-exits invariant), which
+    makes the exits' order by representative the identity; otherwise they
+    are sorted here.
     """
     C = P.assign.shape[0]
-    # exits per representative, and exit rows sorted by representative id
+    # exits per representative, and exit rows ordered by representative id
     ecnt = jnp.zeros((C,), jnp.int32).at[
         jnp.clip(E.orig, 0, C - 1)].add(E.valid.astype(jnp.int32))
-    ekey = jnp.where(E.valid, jnp.clip(E.orig, 0, C - 1), jnp.int32(C))
-    eorder = jnp.argsort(ekey, stable=True)
+    eorder = _exit_order(E, sorted_exits)
     estart = jnp.cumsum(ecnt) - ecnt
     # enumerate (parent, exit) pairs exactly like _expand_step enumerates
     # (row, candidate) pairs: cumsum offsets + searchsorted
@@ -69,8 +75,17 @@ def replay_step(P, active, rep_of_row, E, *, d0: int, d1: int):
                      valid=ok,
                      orig=P.orig[src],
                      lo=P.lo[src], hi=P.hi[src])
-    perm = jnp.argsort(jnp.logical_not(out.valid), stable=True)
-    return type(out)(*(x[perm] for x in out)), needed
+    return compact(out), needed
+
+
+def _exit_order(E, sorted_exits: bool):
+    """Exit row indices ordered by representative id (``orig``), invalid
+    rows last: the identity for sorted exits, a stable sort otherwise."""
+    C = E.orig.shape[0]
+    if sorted_exits:
+        return jnp.arange(C, dtype=jnp.int32)
+    ekey = jnp.where(E.valid, jnp.clip(E.orig, 0, C - 1), jnp.int32(C))
+    return jnp.argsort(ekey, stable=True)
 
 
 @functools.partial(jax.jit, static_argnames=("d0", "d1"))
@@ -102,8 +117,7 @@ def splice_step(P, mask, poff, plen, slab, *, d0: int, d1: int):
                      valid=ok,
                      orig=P.orig[src],
                      lo=P.lo[src], hi=P.hi[src])
-    perm = jnp.argsort(jnp.logical_not(out.valid), stable=True)
-    return type(out)(*(x[perm] for x in out))
+    return compact(out)
 
 
 @jax.jit
@@ -134,9 +148,12 @@ def _stats(C: int, needed, n_spl) -> jnp.ndarray:
     return jnp.stack([needed, n_spl, n_valid])
 
 
-def build(*, d0: int, d1: int, with_replay: bool, with_splice: bool):
+def build(*, d0: int, d1: int, with_replay: bool, with_splice: bool,
+          sorted_exits: bool = True):
     """FOLD step under the registry contract (module docstring): the
-    always-available XLA op-chain composition."""
+    always-available XLA op-chain composition.  ``sorted_exits=False``
+    accepts exit chunks that break the sorted-exits invariant (the static
+    executor's folds over merged continuations)."""
     if not (with_replay or with_splice):
         raise ValueError("FOLD build needs at least one of replay/splice")
 
@@ -145,7 +162,8 @@ def build(*, d0: int, d1: int, with_replay: bool, with_splice: bool):
         def fn(P, active, rep_of_row, E, hit, poff, plen, slab):
             C = P.valid.shape[0]
             cont, needed = replay_step(P, active, rep_of_row, E,
-                                       d0=d0, d1=d1)
+                                       d0=d0, d1=d1,
+                                       sorted_exits=sorted_exits)
             # splice the payload hits, then append after the replay rows —
             # identical row order to the fused kernel's two-region layout
             spl = splice_step(P, hit, poff, plen, slab, d0=d0, d1=d1)
@@ -160,7 +178,8 @@ def build(*, d0: int, d1: int, with_replay: bool, with_splice: bool):
         def fn(P, active, rep_of_row, E):
             C = P.valid.shape[0]
             cont, needed = replay_step(P, active, rep_of_row, E,
-                                       d0=d0, d1=d1)
+                                       d0=d0, d1=d1,
+                                       sorted_exits=sorted_exits)
             return cont, _stats(C, needed, 0)
 
         return fn

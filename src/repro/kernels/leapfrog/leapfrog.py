@@ -22,10 +22,13 @@ uses the branchless binary search in ``ops.py`` instead.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import interpret_default
 
 DEFAULT_BQ = 512     # queries per block
 DEFAULT_BC = 1024    # column elements per block (multiple of 128)
@@ -56,7 +59,7 @@ def _bound_kernel(v_ref, lo_ref, hi_ref, col_ref, out_ref, *,
 def _bound_pallas(col: jnp.ndarray, values: jnp.ndarray,
                   lo: jnp.ndarray, hi: jnp.ndarray, *, strict: bool,
                   block_q: int = DEFAULT_BQ, block_c: int = DEFAULT_BC,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     m = values.shape[0]
     n = col.shape[0]
     if n == 0:
@@ -75,7 +78,7 @@ def _bound_pallas(col: jnp.ndarray, values: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block_q,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((m,), lo.dtype),
-        interpret=interpret,
+        interpret=interpret_default() if interpret is None else interpret,
     )(values.astype(col.dtype), lo.astype(jnp.int32), hi.astype(jnp.int32),
       col)
     return out

@@ -27,18 +27,23 @@ importing an implementation module directly:
     an EMIT frontier into ``(packed, k)`` host-transfer form
     (``kernels/emit/fused.py`` | ``kernels/emit/xla.py``).
 
-Dispatch (``select_expand``/``select_fold``/``select_emit``): a forced
-mode wins (falling back to XLA only if the Pallas build itself raises —
-recorded in ``failures()``); degenerate specs (empty guard trie / empty
-participating relation, where expansion is statically empty) always take
-the XLA path; otherwise ``"auto"`` resolves per spec — on TPU/GPU the
-fused kernel is measured against the XLA chain once per (spec, platform)
-and the winner is cached (the tiny measured-autotune cache,
-:func:`autotune_cache`); on CPU ``"auto"`` picks XLA without measuring
-(interpret mode exists for conformance, not speed — measuring it would
-only burn test time; pass ``measure=True`` to force a measurement
-anywhere).  All three ops share one autotune cache and sidecar; records
-are discriminated by an ``"op"`` field (absent → ``"expand"``, so
+Dispatch (``select_expand``/``select_fold``/``select_emit``): degenerate
+specs (empty guard trie / empty participating relation, where expansion
+is statically empty) always take the XLA path.  Whether a fused kernel is
+*available* is decided by compiling it — ``lower(...).compile()`` at the
+spec's real shapes for the default device — once per (spec, platform);
+a refusal keeps the compiler's message in :func:`failures`.  A forced
+``"pallas"`` that the compiler refuses raises with that message.
+``"auto"`` resolves per spec: on TPU/GPU a refused spec takes the XLA
+chain (with a warning); otherwise the fused kernel is measured against
+the XLA chain once per (spec, platform) and the winner is cached (the
+tiny measured-autotune cache, :func:`autotune_cache`); on CPU ``"auto"``
+picks XLA without compiling or measuring (interpret mode exists for
+conformance, not speed; pass ``measure=True`` to force a measurement
+anywhere).  Selection runs outside any trace: callers that trace the
+kernels into a larger program (``core/distributed.py``) resolve them
+first.  All three ops share one autotune cache and sidecar; records are
+discriminated by an ``"op"`` field (absent → ``"expand"``, so
 pre-FOLD/EMIT sidecars load unchanged).
 """
 from __future__ import annotations
@@ -57,6 +62,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 from .leapfrog import leapfrog, ref as leapfrog_ref
 
@@ -181,11 +187,13 @@ class EmitSpec:
 _SPEC_CLASSES = {"expand": ExpandSpec, "fold": FoldSpec, "emit": EmitSpec}
 _OP_OF_SPEC = {cls: op for op, cls in _SPEC_CLASSES.items()}
 
-# (spec, platform) -> chosen impl; (spec, platform) -> error string
+# (spec, platform) -> chosen impl
 # (spec is an ExpandSpec | FoldSpec | EmitSpec — the dataclasses are
 # distinct types, so one dict cannot collide across ops)
 _AUTOTUNE: Dict[Tuple[object, str], str] = {}
-_FAILURES: Dict[Tuple[object, str], str] = {}
+# (spec, platform) -> None if the fused kernel compiles, else the
+# compiler's message
+_COMPILES: Dict[Tuple[object, str], Optional[str]] = {}
 
 # measured-autotune persistence (ROADMAP follow-on from the kernel PR):
 # autotuning costs one compile+timing of BOTH paths per (spec, platform);
@@ -206,13 +214,15 @@ def autotune_cache() -> Dict[Tuple[ExpandSpec, str], str]:
 
 
 def failures() -> Dict[Tuple[ExpandSpec, str], str]:
-    return dict(_FAILURES)
+    """(spec, platform) -> why the fused kernel is unavailable there."""
+    return {key: f"pallas: {why}" for key, why in _COMPILES.items()
+            if why is not None}
 
 
 def clear_autotune_cache() -> None:
     global _sidecar_loaded
     _AUTOTUNE.clear()
-    _FAILURES.clear()
+    _COMPILES.clear()
     _MEASURED.clear()
     _sidecar_loaded = False
 
@@ -423,15 +433,43 @@ def _time_fn(fn: Callable, args: tuple, reps: int = 2) -> float:
     return best
 
 
+def _compile_target():
+    """The device the fused kernels are compiled for: the default one."""
+    return jax.devices()[0]
+
+
+def _refusal(spec, build_fused: Callable,
+             args: Callable[[], tuple]) -> Optional[str]:
+    """Compile the fused kernel at ``spec``'s real shapes (``args`` builds
+    example inputs; only their shapes are used) for the target device.
+    Returns ``None`` if it compiles, else the compiler's message, which
+    is also recorded in :func:`failures`.  Cached per (spec, platform)."""
+    dev = _compile_target()
+    key = (spec, dev.platform)
+    if key not in _COMPILES:
+        on_dev = SingleDeviceSharding(dev)
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_dev),
+            jax.eval_shape(args))
+        try:
+            jax.jit(build_fused()).lower(*shapes).compile()
+            _COMPILES[key] = None
+        except Exception as e:  # the compiler's refusal, kept verbatim
+            _COMPILES[key] = f"{type(e).__name__}: {e}"
+    return _COMPILES[key]
+
+
 def _select(knob: str, modes: tuple, spec, mode: str,
             platform: Optional[str], measure: Optional[bool],
             builders: Optional[Dict[str, Callable[[], Callable]]],
-            bench_args: Callable[[], tuple]) -> str:
+            bench_args: Callable[[], tuple],
+            compile_args: Callable[[], tuple]) -> str:
     """The shared mode→impl resolution (see :func:`select_expand`).
 
     ``knob`` names the engine knob in error messages; ``bench_args`` is a
     thunk building the measurement inputs (only called when a measurement
-    actually runs)."""
+    actually runs); ``compile_args`` builds inputs of the spec's real
+    shapes for the availability compile."""
     if mode not in modes:
         raise ValueError(f"{knob} must be one of {modes}, got {mode!r}")
     platform = platform or jax.default_backend()
@@ -441,27 +479,36 @@ def _select(knob: str, modes: tuple, spec, mode: str,
     key = (spec, platform)
     if key in _AUTOTUNE:
         return _AUTOTUNE[key]
-    do_measure = (platform in ("tpu", "gpu")) if measure is None else measure
+    accel = platform in ("tpu", "gpu")
+    do_measure = accel if measure is None else measure
+    if builders is not None and (accel or do_measure):
+        why = _refusal(spec, builders["pallas"], compile_args)
+        if why is not None:
+            warnings.warn(f"{knob}: fused kernel refused by the compiler "
+                          f"for {spec}: {why}; falling back to the XLA path")
+            _AUTOTUNE[key] = "xla"
+            return "xla"
     if not do_measure or builders is None:
         # CPU default: the XLA chain; interpret-mode Pallas is a
         # conformance vehicle, not a perf path
         # heuristic, not measured: cached in-process only (persisting it
         # would pre-empt a future measure=True run with a guess)
-        choice = "pallas" if platform in ("tpu", "gpu") else "xla"
+        choice = "pallas" if accel else "xla"
         _AUTOTUNE[key] = choice
         return choice
     args = bench_args()
-    timings: Dict[str, float] = {}
-    for name in ("pallas", "xla"):
-        try:
-            timings[name] = _time_fn(builders[name](), args)
-        except Exception as e:  # pragma: no cover - backend-specific
-            _FAILURES[key] = f"{name}: {e}"
-    choice = min(timings, key=timings.get) if timings else "xla"
+    timings = {name: _time_fn(builders[name](), args)
+               for name in ("pallas", "xla")}
+    choice = min(timings, key=timings.get)
     _AUTOTUNE[key] = choice
     _MEASURED.add(key)
     _maybe_writethrough()
     return choice
+
+
+def _expand_args(spec: ExpandSpec, sizes: Optional[Sequence[int]],
+                 cap: int) -> tuple:
+    return (_measure_chunk(spec, sizes or [1] * spec.n_atoms, cap),)
 
 
 def select_expand(spec: ExpandSpec, mode: str = "auto",
@@ -477,8 +524,8 @@ def select_expand(spec: ExpandSpec, mode: str = "auto",
     cap = min(spec.capacity, 1 << 9)
     return _select(
         "expand_kernel", EXPAND_MODES, spec, mode, platform, measure,
-        builders,
-        lambda: (_measure_chunk(spec, sizes or [1] * spec.n_atoms, cap),))
+        builders, lambda: _expand_args(spec, sizes, cap),
+        lambda: _expand_args(spec, sizes, spec.capacity))
 
 
 def select_fold(spec: FoldSpec, mode: str = "auto",
@@ -489,7 +536,8 @@ def select_fold(spec: FoldSpec, mode: str = "auto",
     """FOLD twin of :func:`select_expand` (``fold_kernel`` knob)."""
     cap = min(spec.capacity, 1 << 9)
     return _select("fold_kernel", KERNEL_MODES, spec, mode, platform,
-                   measure, builders, lambda: _measure_fold_args(spec, cap))
+                   measure, builders, lambda: _measure_fold_args(spec, cap),
+                   lambda: _measure_fold_args(spec, spec.capacity))
 
 
 def select_emit(spec: EmitSpec, mode: str = "auto",
@@ -500,7 +548,8 @@ def select_emit(spec: EmitSpec, mode: str = "auto",
     """EMIT twin of :func:`select_expand` (``emit_kernel`` knob)."""
     cap = min(spec.capacity, 1 << 9)
     return _select("emit_kernel", KERNEL_MODES, spec, mode, platform,
-                   measure, builders, lambda: _measure_emit_args(spec, cap))
+                   measure, builders, lambda: _measure_emit_args(spec, cap),
+                   lambda: _measure_emit_args(spec, spec.capacity))
 
 
 def _maybe_writethrough() -> None:
@@ -547,44 +596,24 @@ def expand_fn(spec: ExpandSpec, *, mode: str = "auto", impl: str = "bsearch",
     chosen = select_expand(
         spec, mode=mode, measure=measure, sizes=sizes,
         builders={"pallas": build_fused, "xla": build_xla})
-    if chosen == "pallas":
-        try:
-            fn = build_fused()
-            # the builder only closes a jitted wrapper — the pallas_call
-            # and its kernel are constructed at trace time, so validate
-            # the trace eagerly (abstract, no compute) or a kernel bug
-            # would only surface at the first call mid-query.  Backend
-            # *compile* failures can still escape this (they are caught
-            # by the autotune measurement on the "auto" path).
-            jax.eval_shape(fn, _measure_chunk(spec, sizes or
-                                              [1] * spec.n_atoms,
-                                              spec.capacity))
-            return fn, "pallas"
-        except Exception as e:  # the always-available fallback
-            _FAILURES[(spec, jax.default_backend())] = f"pallas: {e}"
-            warnings.warn(f"fused EXPAND unavailable for {spec}: {e}; "
-                          "falling back to the XLA path")
-            return build_xla(), "xla"
-    return build_xla(), "xla"
+    return _resolve_built(
+        "EXPAND", spec, chosen, build_fused, build_xla,
+        lambda: _expand_args(spec, sizes, spec.capacity))
 
 
 def _resolve_built(op: str, spec, chosen: str, build_fused: Callable,
-                   build_xla: Callable, bench_args: tuple,
+                   build_xla: Callable, compile_args: Callable[[], tuple],
                    ) -> Tuple[Callable, str]:
-    """Build the chosen impl, validating a Pallas trace eagerly (abstract,
-    no compute) so a kernel bug surfaces here instead of at the first call
-    mid-query; a failed build falls back to the XLA chain (recorded in
-    :func:`failures`).  Backend *compile* failures can still escape the
-    eval_shape (they are caught by the autotune measurement on "auto")."""
+    """Build the chosen impl.  A Pallas choice is compiled first at the
+    spec's real shapes (:func:`_refusal`, cached), so a kernel the
+    compiler refuses raises here with the compiler's message instead of
+    failing at the first call mid-query."""
     if chosen == "pallas":
-        try:
-            fn = build_fused()
-            jax.eval_shape(fn, *bench_args)
-            return fn, "pallas"
-        except Exception as e:  # the always-available fallback
-            _FAILURES[(spec, jax.default_backend())] = f"pallas: {e}"
-            warnings.warn(f"fused {op} unavailable for {spec}: {e}; "
-                          "falling back to the XLA path")
+        why = _refusal(spec, build_fused, compile_args)
+        if why is not None:
+            raise RuntimeError(f"fused {op} refused by the compiler for "
+                               f"{spec}: {why}")
+        return build_fused(), "pallas"
     return build_xla(), "xla"
 
 
@@ -616,7 +645,7 @@ def fold_fn(spec: FoldSpec, *, mode: str = "auto", config=None,
                          builders={"pallas": build_fused, "xla": build_xla})
     return _resolve_built(
         "FOLD", spec, chosen, build_fused, build_xla,
-        _measure_fold_args(spec, spec.capacity))
+        lambda: _measure_fold_args(spec, spec.capacity))
 
 
 def emit_fn(spec: EmitSpec, *, mode: str = "auto", config=None,
@@ -637,7 +666,7 @@ def emit_fn(spec: EmitSpec, *, mode: str = "auto", config=None,
                          builders={"pallas": build_fused, "xla": build_xla})
     return _resolve_built(
         "EMIT", spec, chosen, build_fused, build_xla,
-        _measure_emit_args(spec, spec.capacity))
+        lambda: _measure_emit_args(spec, spec.capacity))
 
 
 # ---------------------------------------------------------------------------
@@ -649,7 +678,7 @@ def emit_fn(spec: EmitSpec, *, mode: str = "auto", config=None,
 _METADATA_PRIMS = frozenset({
     "slice", "squeeze", "reshape", "broadcast_in_dim",
     "convert_element_type", "transpose", "copy"})
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "remat", "custom_jvp_call",
+_CALL_PRIMS = ("jit", "closed_call", "remat2", "custom_jvp_call",
                "custom_vjp_call", "custom_vjp_call_jaxpr")
 
 

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import tree_map_with_path
 from ..configs import ARCHS, get_arch
 from ..models import Model
 from ..optim import adamw
@@ -136,7 +135,7 @@ def cache_shardings(cache_struct, mesh):
                 spec.append(ps[0])
         return NamedSharding(mesh, P(*spec))
 
-    return tree_map_with_path(leaf, cache_struct)
+    return jax.tree.map_with_path(leaf, cache_struct)
 
 
 # ---------------------------------------------------------------------------

@@ -6,12 +6,13 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model_parallel: int = 1):
@@ -19,4 +20,4 @@ def make_local_mesh(model_parallel: int = 1):
     n = len(jax.devices())
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"), (AxisType.Auto,) * 2)

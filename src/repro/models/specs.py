@@ -15,7 +15,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..compat import tree_flatten_with_path
 from ..configs.base import ArchConfig
 
 Logical = Tuple[Optional[str], ...]
@@ -218,7 +217,7 @@ def _init_leaf(s: ParamSpec, key) -> jnp.ndarray:
 
 def init_params(specs, key) -> Dict:
     """Deterministic init: every leaf gets a key derived from its path."""
-    flat, treedef = tree_flatten_with_path(specs, is_leaf=is_spec)
+    flat, treedef = jax.tree.flatten_with_path(specs, is_leaf=is_spec)
     leaves = []
     for path, s in flat:
         name = "/".join(str(p) for p in path)

@@ -11,20 +11,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
-@pytest.fixture(autouse=True, scope="module")
-def _clear_jax_caches_between_modules():
-    """Drop compiled-executable caches after every test module.  The full
-    suite accumulates hundreds of distinct jit compilations; on CPU the
-    XLA client has been observed to segfault inside ``backend_compile``
-    late in a long single-process run (state-dependent — every module
-    passes in isolation).  Bounding cache growth keeps the one-process
-    tier-1 sweep stable; engines re-trace on demand, so this costs only
-    recompilation time at module boundaries."""
-    yield
-    import jax
-    jax.clear_caches()
-
-
 @pytest.fixture(scope="session")
 def small_graphs():
     """A few deterministic small graph databases."""

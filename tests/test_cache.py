@@ -8,8 +8,8 @@ what the dynamic sizing controller steers on, so it is load-bearing."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import (CacheConfig, CachePolicy, choose_plan, clftj_count,
                         cycle_query, lftj_count, lollipop_query, star_query)
@@ -84,7 +84,7 @@ def test_dynamic_sizing_respects_budget_and_resizes(small_graphs):
 def test_device_cache_set_fills_all_ways_and_hits():
     """A batch of same-set keys must fill every way, not just one (the
     multi-round insert), and then hit on re-probe."""
-    with enable_x64():
+    with jax.enable_x64(True):
         from repro.core.cache import _hash_sets
         cfg = CacheConfig(policy="setassoc", slots=16, assoc=4)
         t = DeviceCache.create(cfg)
@@ -105,7 +105,7 @@ def test_device_cache_set_fills_all_ways_and_hits():
 
 
 def test_device_cache_lru_evicts_oldest():
-    with enable_x64():
+    with jax.enable_x64(True):
         from repro.core.cache import _hash_sets
         cfg = CacheConfig(policy="setassoc", slots=8, assoc=2)
         t = DeviceCache.create(cfg)
@@ -131,7 +131,7 @@ def test_device_cache_lru_evicts_oldest():
 
 
 def test_device_cache_costaware_protects_expensive():
-    with enable_x64():
+    with jax.enable_x64(True):
         from repro.core.cache import _hash_sets
         cfg = CacheConfig(policy="costaware", slots=4, assoc=1)
         t = DeviceCache.create(cfg)
@@ -216,7 +216,7 @@ def _np(x):
 def test_payload_roundtrip_and_count_only_miss():
     """A payload insert is hit by probe_payload; a count-only insert on the
     same table is NOT (the -1 sentinel) while the plain probe still hits."""
-    with enable_x64():
+    with jax.enable_x64(True):
         t = _payload_table()
         keys = jnp.asarray([3, 4], jnp.int64)
         active = jnp.asarray([True, True])
@@ -248,7 +248,7 @@ def test_payload_roundtrip_and_count_only_miss():
 def test_payload_flush_on_arena_exhaustion():
     """When a batch exceeds the remaining arena the table epoch-flushes:
     every payload is invalidated, keys/counts stay resident."""
-    with enable_x64():
+    with jax.enable_x64(True):
         t = _payload_table(payload_rows=8)
         k1 = jnp.asarray([11, 12], jnp.int64)
         lens = jnp.asarray([4, 4], jnp.int64)
@@ -274,7 +274,7 @@ def test_payload_eviction_invalidates_block_metadata():
     """An evicting write must take the payload planes with it: after a
     count-only insert evicts a payload entry (direct-mapped, same set),
     the new key must not inherit the victim's block."""
-    with enable_x64():
+    with jax.enable_x64(True):
         cfg = CacheConfig(policy="direct", slots=1, cache_payloads=True,
                           payload_rows=16)
         t = DeviceCache.create(cfg)
@@ -299,7 +299,7 @@ def test_payload_eviction_invalidates_block_metadata():
 def test_payload_attaches_to_count_only_resident():
     """A payload-bearing insert may refresh a key first seen by count():
     afterwards the payload probe hits it."""
-    with enable_x64():
+    with jax.enable_x64(True):
         t = _payload_table(slots=8, assoc=2)
         one = jnp.asarray([True])
         k = jnp.asarray([31], jnp.int64)
@@ -398,7 +398,7 @@ def test_alloc_oversized_block_neither_flushes_nor_vetoes():
     """A block larger than the whole arena is refused outright: it must
     not epoch-flush resident payloads nor veto admissible candidates
     behind it in the same batch."""
-    with enable_x64():
+    with jax.enable_x64(True):
         t = _payload_table(payload_rows=8)
         t.alloc_blocks(np.asarray([3]), np.asarray([True]))  # bump = 3
         # a never-fit block alone must not flush resident payloads

@@ -13,6 +13,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def _run(code: str, devices: int = 8, timeout: int = 560) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
+    env["JAX_PLATFORMS"] = "cpu"  # the parent may hold the chip
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={devices}")
     out = subprocess.run([sys.executable, "-c", code], env=env,
@@ -91,9 +92,10 @@ from repro.configs import get_arch
 from repro.models import Model
 from repro.train.train_step import TrainConfig, init_train_state, make_train_step, state_shardings
 from repro.sharding import rules as shr
+from repro.launch.mesh import make_local_mesh
 cfg = get_arch('minitron-8b-smoke')
 model = Model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_local_mesh(model_parallel=4)
 with mesh:
     state = init_train_state(model, jax.random.PRNGKey(0))
     shards = state_shardings(model, mesh)

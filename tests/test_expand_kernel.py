@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import choose_plan, cycle_query, star_query, engine
 from repro.core.cached_frontier import JaxCachedTrieJoin
@@ -86,7 +85,7 @@ def test_fused_matches_xla_and_oracle_level_by_level(qname, q):
     db = _db(seed=11, nv=8, ne=90)
     td, order = choose_plan(q, db.stats())
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 10)
-    with enable_x64():
+    with jax.enable_x64(True):
         F = eng.initial_frontier()
         oracle_checked = 0
         for d in range(eng.n):
@@ -105,7 +104,7 @@ def test_empty_frontier():
     q = cycle_query(3)
     td, order = choose_plan(q, db.stats())
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 7)
-    with enable_x64():
+    with jax.enable_x64(True):
         F = eng.initial_frontier()
         F = F._replace(valid=jnp.zeros_like(F.valid))
         fx, fp, _ = _build_pair(eng, 0)
@@ -126,7 +125,7 @@ def test_single_atom_guard_depth():
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 10)
     solo = [d for d in range(eng.n) if len(eng.at_depth[d]) == 1]
     assert solo, "star query must have single-atom depths"
-    with enable_x64():
+    with jax.enable_x64(True):
         F = eng.initial_frontier()
         for d in range(eng.n):
             fx, fp, a = _build_pair(eng, d)
@@ -148,7 +147,7 @@ def test_duplicate_keys_heavy():
     q = cycle_query(4)
     td, order = choose_plan(q, db.stats())
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 8)
-    with enable_x64():
+    with jax.enable_x64(True):
         F = eng.initial_frontier()
         for d in range(eng.n):
             fx, fp, a = _build_pair(eng, d)
@@ -168,7 +167,7 @@ def test_parity_x64_on_and_off(x64):
     q = cycle_query(3)
     td, order = choose_plan(q, db.stats())
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 8)
-    ctx = enable_x64() if x64 else _null()
+    ctx = jax.enable_x64(True) if x64 else _null()
     with ctx:
         F = eng.initial_frontier()
         want_factor = jnp.int64 if x64 else jnp.int32
@@ -202,7 +201,7 @@ def test_block_q_config_snaps_to_divisor(cap, block_q):
     q = cycle_query(3)
     td, order = choose_plan(q, db.stats())
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=cap)
-    with enable_x64():
+    with jax.enable_x64(True):
         F = eng.initial_frontier()
         for d in range(eng.n):
             fx, fp, _ = _build_pair(eng, d, config=cfg)
@@ -257,10 +256,10 @@ def test_degenerate_spec_takes_xla_even_when_pallas_forced():
 
 
 def test_pallas_build_failure_falls_back_to_xla(monkeypatch):
-    """The always-available fallback must engage at *build* time: the
-    registry trace-validates the fused fn (eval_shape), so a kernel that
-    cannot trace is recorded in failures() and the engine runs the XLA
-    chain instead of dying mid-query."""
+    """A fused EXPAND the compiler refuses: ``"auto"`` falls back to the
+    XLA chain at build time, with a warning and the compiler's message in
+    failures(); a forced ``"pallas"`` raises with that message instead of
+    falling back."""
     from repro.kernels.expand import fused as fused_real
 
     def broken_build(**kw):
@@ -273,13 +272,24 @@ def test_pallas_build_failure_falls_back_to_xla(monkeypatch):
     db = _db(seed=29)
     q = cycle_query(3)
     td, order = choose_plan(q, db.stats())
-    with pytest.warns(UserWarning, match="falling back to the XLA path"):
-        eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 7,
-                                expand_kernel="pallas")
-        want = engine.count(q, db, td=td, order=order, capacity=1 << 7).count
-        assert eng.count() == want
-    assert all(v == "xla" for v in eng.expand_paths.values())
-    assert registry.failures(), "failure must be recorded"
+    eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 7)
+    a = eng.expand_kernel_args(0)
+    with jax.enable_x64(True):
+        with pytest.warns(UserWarning, match="falling back to the XLA path"):
+            fn, chosen = registry.expand_fn(_spec(eng, 0), mode="auto",
+                                            measure=True, sizes=eng.sizes,
+                                            **a)
+        assert chosen == "xla"
+        F = eng.initial_frontier()
+        _assert_parity(*fn(F), *xla_mod.build(impl="bsearch", **a)(F),
+                       msg="fallback")
+    assert any("mosaic lowering exploded" in why
+               for why in registry.failures().values())
+    forced = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 7,
+                               expand_kernel="pallas")
+    with pytest.raises(RuntimeError, match="refused by the compiler"
+                                           ".*mosaic lowering exploded"):
+        forced.count()
     registry.clear_autotune_cache()
 
 
@@ -295,7 +305,7 @@ def test_autotune_measured_caches_choice():
         "xla": lambda: xla_mod.build(impl="bsearch", **a),
         "pallas": lambda: fused_mod.build(**a),
     }
-    with enable_x64():
+    with jax.enable_x64(True):
         choice = registry.select_expand(spec, mode="auto", measure=True,
                                         builders=builders, sizes=eng.sizes)
     assert choice in ("pallas", "xla")
@@ -318,7 +328,7 @@ def test_fused_is_at_most_two_device_ops():
     q = cycle_query(4)
     td, order = choose_plan(q, db.stats())
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=1 << 8)
-    with enable_x64():
+    with jax.enable_x64(True):
         F = eng.initial_frontier()
         for d in range(eng.n):
             fx, fp, _ = _build_pair(eng, d)

@@ -18,7 +18,6 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import choose_plan, cycle_query, engine
 from repro.core.cached_frontier import JaxCachedTrieJoin
@@ -143,7 +142,7 @@ def _fold_args(inputs, with_replay, with_splice):
 @pytest.mark.parametrize("seed", [0, 7])
 def test_fold_fused_matches_xla_and_oracle(name, wr, ws, seed):
     inputs = _fold_inputs(seed=seed)
-    with enable_x64():
+    with jax.enable_x64(True):
         fx = fxla.build(d0=D0, d1=D1, with_replay=wr, with_splice=ws)
         fp = ffused.build(d0=D0, d1=D1, with_replay=wr, with_splice=ws)
         args = _fold_args(inputs, wr, ws)
@@ -163,7 +162,7 @@ def test_empty_fold(name, wr, ws):
     inputs = (P, jnp.zeros_like(active),
               rep_of_row, E._replace(valid=jnp.zeros_like(E.valid)),
               jnp.zeros_like(hit), poff, plen, slab)
-    with enable_x64():
+    with jax.enable_x64(True):
         fx = fxla.build(d0=D0, d1=D1, with_replay=wr, with_splice=ws)
         fp = ffused.build(d0=D0, d1=D1, with_replay=wr, with_splice=ws)
         args = _fold_args(inputs, wr, ws)
@@ -186,7 +185,7 @@ def test_fold_overflow_flag():
     active = jnp.ones((C,), bool)
     rep_of_row = jnp.zeros((C,), jnp.int32)
     E = _frontier(rng, C, np.zeros((C,), np.int32))
-    with enable_x64():
+    with jax.enable_x64(True):
         fx = fxla.build(d0=D0, d1=D1, with_replay=True, with_splice=False)
         fp = ffused.build(d0=D0, d1=D1, with_replay=True, with_splice=False)
         rx = fx(P, active, rep_of_row, E)
@@ -210,7 +209,7 @@ def test_fold_parity_x64_on_and_off(x64):
         def __exit__(self, *a):
             return False
 
-    with (enable_x64() if x64 else _null()):
+    with (jax.enable_x64(True) if x64 else _null()):
         inputs = _fold_inputs(seed=5)
         fx = fxla.build(d0=D0, d1=D1, with_replay=True, with_splice=True)
         fp = ffused.build(d0=D0, d1=D1, with_replay=True, with_splice=True)
@@ -224,7 +223,7 @@ def test_fold_block_q_configs(block_q):
     """Odd and oversized block sizes still produce bit-exact output."""
     cfg = FusedFoldConfig(block_q=block_q)
     inputs = _fold_inputs(seed=13)
-    with enable_x64():
+    with jax.enable_x64(True):
         fx = fxla.build(d0=D0, d1=D1, with_replay=True, with_splice=True)
         fp = ffused.build(d0=D0, d1=D1, with_replay=True, with_splice=True,
                           config=cfg)
@@ -246,7 +245,7 @@ def test_emit_fused_matches_xla_and_oracle(density):
     if density == 1.0:
         valid[:] = True
     want = emit_ref(assign, valid)
-    with enable_x64():
+    with jax.enable_x64(True):
         ex_, ep = exla.build(), efused.build()
         for name, fn in (("xla", ex_), ("pallas", ep)):
             packed, k = fn(jnp.asarray(assign), jnp.asarray(valid))
@@ -301,9 +300,9 @@ def test_engine_knob_validation():
 
 
 def test_fold_pallas_build_failure_falls_back_to_xla(monkeypatch):
-    """The always-available fallback at *build* time: a fused FOLD/EMIT
-    that cannot trace is recorded in failures() and the engine runs the
-    XLA chain instead of dying mid-query."""
+    """A fused FOLD/EMIT the compiler refuses: ``"auto"`` falls back to
+    the XLA chain at build time, recorded in failures(); a forced
+    ``"pallas"`` raises with the compiler's message instead."""
     def broken_build(**kw):
         def fn(*a):
             raise RuntimeError("mosaic lowering exploded")
@@ -312,13 +311,14 @@ def test_fold_pallas_build_failure_falls_back_to_xla(monkeypatch):
     registry.clear_autotune_cache()
     monkeypatch.setattr(ffused, "build", broken_build)
     monkeypatch.setattr(efused, "build", broken_build)
-    with enable_x64():
+    with jax.enable_x64(True):
         with pytest.warns(UserWarning, match="falling back to the XLA path"):
-            fn, chosen = registry.fold_fn(_fspec(), mode="pallas",
-                                          d0=D0, d1=D1)
+            fn, chosen = registry.fold_fn(_fspec(), mode="auto",
+                                          measure=True, d0=D0, d1=D1)
         assert chosen == "xla"
         with pytest.warns(UserWarning, match="falling back to the XLA path"):
-            efn, echosen = registry.emit_fn(_espec(), mode="pallas")
+            efn, echosen = registry.emit_fn(_espec(), mode="auto",
+                                            measure=True)
         assert echosen == "xla"
         assert registry.failures(), "failures must be recorded"
         # the fallbacks actually run
@@ -327,6 +327,10 @@ def test_fold_pallas_build_failure_falls_back_to_xla(monkeypatch):
         assert int(np.asarray(stats)[2]) == int(np.asarray(F.valid).sum())
         packed, k = efn(inputs[0].assign, inputs[0].valid)
         assert int(k) == int(np.asarray(inputs[0].valid).sum())
+        with pytest.raises(RuntimeError, match="mosaic lowering exploded"):
+            registry.fold_fn(_fspec(), mode="pallas", d0=D0, d1=D1)
+        with pytest.raises(RuntimeError, match="mosaic lowering exploded"):
+            registry.emit_fn(_espec(), mode="pallas")
     registry.clear_autotune_cache()
 
 
@@ -339,7 +343,7 @@ def test_fold_autotune_measured_caches_choice():
         "pallas": lambda: ffused.build(d0=D0, d1=D1, with_replay=True,
                                        with_splice=True),
     }
-    with enable_x64():
+    with jax.enable_x64(True):
         choice = registry.select_fold(spec, mode="auto", measure=True,
                                       builders=builders)
     assert choice in ("pallas", "xla")
@@ -357,7 +361,7 @@ def test_autotune_entries_roundtrip_with_op_field():
     schema; EXPAND records keep the historical op-less shape, and all
     three merge back into an empty cache."""
     registry.clear_autotune_cache()
-    with enable_x64():
+    with jax.enable_x64(True):
         registry.select_fold(_fspec(), mode="auto", measure=True, builders={
             "xla": lambda: fxla.build(d0=D0, d1=D1, with_replay=True,
                                       with_splice=True),
@@ -403,7 +407,7 @@ def test_fold_fused_is_at_most_two_device_ops(name, wr, ws):
     non-metadata device ops (the pallas_call + the int64 stats cast is
     metadata); the XLA chain is an order of magnitude more."""
     inputs = _fold_inputs(seed=23)
-    with enable_x64():
+    with jax.enable_x64(True):
         fx = fxla.build(d0=D0, d1=D1, with_replay=wr, with_splice=ws)
         fp = ffused.build(d0=D0, d1=D1, with_replay=wr, with_splice=ws)
         args = _fold_args(inputs, wr, ws)
@@ -419,7 +423,7 @@ def test_emit_fused_is_at_most_two_device_ops():
     rng = np.random.default_rng(29)
     assign = jnp.asarray(rng.integers(0, 9, size=(C, N)).astype(np.int32))
     valid = jnp.asarray(rng.random(C) < 0.5)
-    with enable_x64():
+    with jax.enable_x64(True):
         n_fused = registry.device_op_count(efused.build(), assign, valid)
         n_xla = registry.device_op_count(exla.build(), assign, valid)
         assert n_fused <= 2, f"fused EMIT lowers to {n_fused} ops"

@@ -387,7 +387,9 @@ def test_snapshot_from_other_process_serves_warm(db, tmp_path):
     snap = str(tmp_path / "serve_snap.npz")
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     code = _WRITER.format(src=src, snap=snap)
+    # the writer forces the CPU: this process may hold the chip
     proc = subprocess.run([sys.executable, "-c", code],
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"},
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "WROTE" in proc.stdout
