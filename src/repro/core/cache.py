@@ -48,7 +48,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field, replace
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -585,13 +585,21 @@ class DeviceCache:
                           rounds=min(self.config.ways, 8))
             self.keys, self.vals, self.used, self.stamp, self.cost = out[:5]
 
-    def stats(self) -> Dict[str, int]:
-        acc = device_get(
-            {"hits": self._acc_hits, "misses": self._acc_misses,
-             "probes": self._acc_probes, "inserts": self._acc_inserts,
-             "evictions": self._acc_evictions,
-             "payload_hits": self._acc_payload_hits,
-             "occupancy": jnp.sum(self.used)}, "cache-stats")
+    def accumulators(self) -> Dict[str, jnp.ndarray]:
+        """The device-side counters that :meth:`stats` reports."""
+        return {"hits": self._acc_hits, "misses": self._acc_misses,
+                "probes": self._acc_probes, "inserts": self._acc_inserts,
+                "evictions": self._acc_evictions,
+                "payload_hits": self._acc_payload_hits,
+                "occupancy": jnp.sum(self.used)}
+
+    def stats(self, acc: Optional[Dict[str, Any]] = None
+              ) -> Dict[str, int]:
+        """Counters of this table.  ``acc`` holds the host values of
+        :meth:`accumulators` where a caller fetched them with another
+        sync; without it they are fetched here (``cache-stats``)."""
+        if acc is None:
+            acc = device_get(self.accumulators(), "cache-stats")
         out = {k: int(v) for k, v in acc.items()}
         out["resizes"] = self.resizes
         out["slots"] = self.n_slots
@@ -759,13 +767,16 @@ class CacheManager:
                 headroom -= missing * self.config.initial_slots()
         return t.maybe_resize(headroom)
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self, accs: Optional[Dict[int, Dict[str, Any]]] = None
+              ) -> Dict[str, int]:
+        """Counters summed over the tables; ``accs`` maps a node to its
+        table's fetched accumulators (see :meth:`DeviceCache.stats`)."""
         agg = {"hits": 0, "misses": 0, "probes": 0, "inserts": 0,
                "evictions": 0, "resizes": 0, "slots": 0, "occupancy": 0,
                "payload_hits": 0, "payload_flushes": 0, "payload_skips": 0,
                "payload_throttled": 0, "slab_rows": 0}
-        for t in self.tables.values():
-            for k, val in t.stats().items():
+        for v, t in self.tables.items():
+            for k, val in t.stats((accs or {}).get(v)).items():
                 agg[k] = agg.get(k, 0) + val
         return agg
 

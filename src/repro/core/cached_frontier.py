@@ -115,6 +115,8 @@ class JaxCachedTrieJoin(JaxTrieJoin):
                       "tier2_replay_hits": 0, "tier2_payload_flushes": 0,
                       "tier2_payload_skips": 0, "tier2_payload_throttled": 0,
                       "tier2_slab_rows": 0, "subtree_launches": 0,
+                      "expand_rows_in": 0, "expand_candidates": 0,
+                      "expand_rows_out": 0,
                       "expand_calls_pallas": 0, "expand_calls_xla": 0,
                       "fold_calls_pallas": 0, "fold_calls_xla": 0,
                       "emit_calls_pallas": 0, "emit_calls_xla": 0}
@@ -132,7 +134,7 @@ class JaxCachedTrieJoin(JaxTrieJoin):
         return len(self.plan.adhesion_idx[v]) <= 2
 
     def _finalize(self, ex: ScheduleExecutor) -> None:
-        agg = self.cache.stats()
+        agg = self.cache.stats(ex.cache_accumulators())
         self.stats["tier2_hits"] = agg["hits"]
         self.stats["tier2_misses"] = agg["misses"]
         self.stats["tier2_probes"] = agg["probes"]
@@ -148,6 +150,8 @@ class JaxCachedTrieJoin(JaxTrieJoin):
         self.stats["tier2_slab_rows"] = agg.get("slab_rows", 0)
         self.stats["tier1_rows_collapsed"] += ex.t1_rows_collapsed()
         self.stats["subtree_launches"] += ex.subtree_launches
+        for k in ("expand_rows_in", "expand_candidates", "expand_rows_out"):
+            self.stats[k] += getattr(ex, k)
         for path, runs in ex.expand_path_runs.items():
             self.stats[f"expand_calls_{path}"] = (
                 self.stats.get(f"expand_calls_{path}", 0) + runs)

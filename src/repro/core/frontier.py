@@ -42,6 +42,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..kernels import registry as kernels
 from .cq import CQ
@@ -302,38 +303,42 @@ class JaxTrieJoin:
         (each piece reaches the device only when the caller takes it).
         Vectorized: a few array passes per chunk, no per-row Python work.
         """
-        C = self.capacity
-        g_ai, rs, n_rows_g = self.expand_plan(d)
-        idx = np.flatnonzero(host["valid"])
-        lo_g = host["lo"][idx, g_ai].astype(np.int64)
-        hi_g = host["hi"][idx, g_ai].astype(np.int64)
-        r0 = np.searchsorted(rs, lo_g, side="left")
-        r1 = np.searchsorted(rs, hi_g, side="left")
-        big = counts[idx] > C
-        # entries: one per whole row, ceil(runs / C) per oversized row
-        n_seg = np.where(big, -(-(r1 - r0) // C), 1)
-        row = np.repeat(idx, n_seg)
-        seg = np.arange(row.size) - np.repeat(np.cumsum(n_seg) - n_seg,
-                                              n_seg)
-        split = np.repeat(big, n_seg)
-        a = np.repeat(r0, n_seg) + seg * C
-        b = np.minimum(a + C, np.repeat(r1, n_seg))
-        ext = np.append(rs, n_rows_g).astype(np.int64)
-        e_lo = np.where(split, ext[np.minimum(a, len(rs))],
-                        np.repeat(lo_g, n_seg))
-        e_hi = np.where(split, ext[np.minimum(b, len(rs))],
-                        np.repeat(hi_g, n_seg))
-        cnt = np.where(split, b - a, np.repeat(r1 - r0, n_seg))
+        with TraceAnnotation("clftj.morsel_split"):
+            C = self.capacity
+            g_ai, rs, n_rows_g = self.expand_plan(d)
+            idx = np.flatnonzero(host["valid"])
+            lo_g = host["lo"][idx, g_ai].astype(np.int64)
+            hi_g = host["hi"][idx, g_ai].astype(np.int64)
+            r0 = np.searchsorted(rs, lo_g, side="left")
+            r1 = np.searchsorted(rs, hi_g, side="left")
+            big = counts[idx] > C
+            # entries: one per whole row, ceil(runs / C) per oversized row
+            n_seg = np.where(big, -(-(r1 - r0) // C), 1)
+            row = np.repeat(idx, n_seg)
+            seg = np.arange(row.size) - np.repeat(np.cumsum(n_seg) - n_seg,
+                                                  n_seg)
+            split = np.repeat(big, n_seg)
+            a = np.repeat(r0, n_seg) + seg * C
+            b = np.minimum(a + C, np.repeat(r1, n_seg))
+            ext = np.append(rs, n_rows_g).astype(np.int64)
+            e_lo = np.where(split, ext[np.minimum(a, len(rs))],
+                            np.repeat(lo_g, n_seg))
+            e_hi = np.where(split, ext[np.minimum(b, len(rs))],
+                            np.repeat(hi_g, n_seg))
+            cnt = np.where(split, b - a, np.repeat(r1 - r0, n_seg))
+            cum = np.concatenate([[0], np.cumsum(cnt)])
         # greedy pack: a piece grows while its rows <= C and count <= C
-        cum = np.concatenate([[0], np.cumsum(cnt)])
+        # (each piece's host work is one span, closed before its yield)
         p = 0
         while p < row.size:
-            q = int(np.searchsorted(cum, cum[p] + C, side="right")) - 1
-            q = max(p + 1, min(q, p + C, row.size))
-            fields = {k: v[row[p:q]] for k, v in host.items()}
-            fields["lo"][:, g_ai] = e_lo[p:q]
-            fields["hi"][:, g_ai] = e_hi[p:q]
-            yield self._pack_rows(fields, q - p)
+            with TraceAnnotation("clftj.morsel_split"):
+                q = int(np.searchsorted(cum, cum[p] + C, side="right")) - 1
+                q = max(p + 1, min(q, p + C, row.size))
+                fields = {k: v[row[p:q]] for k, v in host.items()}
+                fields["lo"][:, g_ai] = e_lo[p:q]
+                fields["hi"][:, g_ai] = e_hi[p:q]
+                piece = self._pack_rows(fields, q - p)
+            yield piece
             p = q
 
     def _pack_rows(self, fields: Dict[str, np.ndarray], n: int) -> Frontier:

@@ -21,7 +21,7 @@ Accounting rules (budget-tested):
 * ``SyncCounter.count`` counts **blocking** syncs only — the number that
   must stay O(ops).
 * an async *issue* increments ``SyncCounter.async_count`` and rides
-  ``events``/``label_counts`` under its own label (e.g. ``emit-stream``),
+  ``label_counts`` under its own label (e.g. ``emit-stream``),
   so in-flight fetches are visible separately and a test can pin their
   frequency without conflating them with blocking syncs.
 * *completing* an async fetch (``AsyncFetch.get``) is not a counted event:
@@ -29,6 +29,12 @@ Accounting rules (budget-tested):
 * counter scopes are **thread-local**: a ``SyncCounter`` only observes
   syncs issued by the thread that entered it (the serving layer budgets
   each session's worker-thread execution independently).
+
+Every sync is also a host span in a profiler trace, on the device's
+clock: ``clftj.sync.<label>`` around a blocking fetch,
+``clftj.fetch_issue.<label>`` around an async issue and
+``clftj.fetch_wait.<label>`` around its completion
+(``jax.profiler.TraceAnnotation``: one check when no trace is active).
 """
 from __future__ import annotations
 
@@ -38,6 +44,7 @@ from typing import Any, Deque, Dict, Iterator, List, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # Counter scopes are PER THREAD: the serving layer (repro/serve) runs many
 # client sessions against one process, and a SyncCounter opened around one
@@ -59,18 +66,16 @@ class SyncCounter:
     may fetch a whole pytree — that is the point: one batched fetch per op,
     not one per chunk).  ``async_count`` is the number of
     :func:`device_get_async` issues (non-blocking; the copy overlaps device
-    work).  ``events`` records the labels of both, for diagnosing
-    regressions; ``label_counts`` is the same information aggregated, so
-    budget tests can pin one label's frequency (e.g. the evaluation-mode
-    payload plan must ride the per-fold ``replay-plan`` fetch — O(ops),
-    not O(hits) — and streaming emission must issue ``emit-stream``
-    fetches asynchronously, never as blocking syncs).
-    """
+    work).  ``label_counts`` counts both per label, for diagnosing
+    regressions and so budget tests can pin one label's frequency (e.g.
+    the evaluation-mode payload plan must ride the per-fold
+    ``replay-plan`` fetch — O(ops), not O(hits) — and streaming emission
+    must issue ``emit-stream`` fetches asynchronously, never as blocking
+    syncs)."""
 
     def __init__(self) -> None:
         self.count = 0
         self.async_count = 0
-        self.events: List[str] = []
         self.label_counts: Counter = Counter()
 
     def __enter__(self) -> "SyncCounter":
@@ -86,9 +91,9 @@ def device_get(tree: Any, label: str = "") -> Any:
     """``jax.device_get`` with sync accounting (one event per call)."""
     for c in _active():
         c.count += 1
-        c.events.append(label)
         c.label_counts[label] += 1
-    return jax.device_get(tree)
+    with TraceAnnotation(f"clftj.sync.{label}"):
+        return jax.device_get(tree)
 
 
 # ---------------------------------------------------------------------------
@@ -123,7 +128,8 @@ class AsyncFetch:
         return True
 
     def get(self) -> Any:
-        return jax.device_get(self.tree)
+        with TraceAnnotation(f"clftj.fetch_wait.{self.label}"):
+            return jax.device_get(self.tree)
 
 
 def device_get_async(tree: Any, label: str = "") -> AsyncFetch:
@@ -133,19 +139,20 @@ def device_get_async(tree: Any, label: str = "") -> AsyncFetch:
     an :class:`AsyncFetch`.  Counted as an *async* event (see the module
     docstring's accounting rules): ``SyncCounter.async_count`` and
     ``label_counts[label]`` advance, ``count`` does not."""
-    for leaf in jax.tree.leaves(tree):
-        if isinstance(leaf, jax.Array):
-            try:
-                leaf.copy_to_host_async()
-            except (NotImplementedError, AttributeError):
-                # backend without D2H async: .get() still works, it just
-                # blocks on the transfer.  Real failures (deleted/donated
-                # buffers, ...) must surface HERE, not at some later
-                # unrelated .get() — so only the unsupported cases pass.
-                pass
+    with TraceAnnotation(f"clftj.fetch_issue.{label}"):
+        for leaf in jax.tree.leaves(tree):
+            if isinstance(leaf, jax.Array):
+                try:
+                    leaf.copy_to_host_async()
+                except (NotImplementedError, AttributeError):
+                    # backend without D2H async: .get() still works, it
+                    # just blocks on the transfer.  Real failures
+                    # (deleted/donated buffers, ...) must surface HERE,
+                    # not at some later unrelated .get() — so only the
+                    # unsupported cases pass.
+                    pass
     for c in _active():
         c.async_count += 1
-        c.events.append(label)
         c.label_counts[label] += 1
     return AsyncFetch(tree, label)
 

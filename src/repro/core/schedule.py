@@ -48,6 +48,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..kernels.expand.xla import valid_first
 from ..kernels.fold.xla import (_exit_order,
@@ -399,6 +400,16 @@ class ScheduleExecutor:
     representatives' fresh blocks (DESIGN.md §2.6).  Count-only tables
     are still bypassed — caching stays an optimization, never a
     correctness requirement.
+
+    Instrumentation rides what the executor does anyway.  Each op
+    execution is a host span in a profiler trace (``clftj.op.expand``,
+    ``clftj.op.enter``, ``clftj.op.fold``, ``clftj.op.emit``; admission
+    is ``clftj.admit``), closed before any ``yield``.  The EXPAND
+    counters (``expand_rows_in``, ``expand_candidates``,
+    ``expand_rows_out``) are read from the planning and admission
+    fetches, and the device-side counters (tier-1 collapses, tier-2
+    accumulators) ride the pass's last fetch where it has one
+    (``emit-total``, ``emit-rows``): no counter adds a sync.
     """
 
     def __init__(self, engine, mode: str = "count"):
@@ -418,7 +429,14 @@ class ScheduleExecutor:
                 self._bracket[open_pcs.pop()] = pc
         self._total = jnp.zeros((), jnp.int64)
         self._t1_collapsed = jnp.zeros((), jnp.int64)
+        # host values of _device_stats(), when they rode the last fetch
+        self._stats_h: Optional[Tuple[Any, Dict[int, Dict[str, Any]]]] = None
         self.subtree_launches = 0
+        # EXPAND work: valid rows entering, (row, candidate) pairs laid
+        # out, valid rows surviving — all read from fetches made anyway
+        self.expand_rows_in = 0
+        self.expand_candidates = 0
+        self.expand_rows_out = 0
         # op-execution counters: span interiors re-run once per parent
         # morsel, so the sync budget scales with these, never with the
         # number of chunks inside one op execution
@@ -447,7 +465,9 @@ class ScheduleExecutor:
     def count(self) -> int:
         for _ in self._iter_emitted():
             pass
-        return int(device_get(self._total, "emit-total"))
+        total, self._stats_h = device_get(
+            (self._total, self._device_stats()), "emit-total")
+        return int(total)
 
     def evaluate(self) -> Iterator[np.ndarray]:
         """Yields (k, n) int32 blocks of result assignments (order cols).
@@ -459,7 +479,8 @@ class ScheduleExecutor:
             self._emitted.extend(pairs)
         if not self._emitted:
             return
-        blocks = device_get(self._emitted, "emit-rows")
+        blocks, self._stats_h = device_get(
+            (self._emitted, self._device_stats()), "emit-rows")
         for packed, k in blocks:
             k = int(k)
             if k:
@@ -517,8 +538,23 @@ class ScheduleExecutor:
         # backing host array is recycled by a later fetch
         return np.array(np.asarray(packed)[:k])
 
+    def _device_stats(self):
+        """The device-side counters: tier-1 collapses and every tier-2
+        table's accumulators (fetched with the pass's last sync)."""
+        tables = self.cache.tables if self.cache is not None else {}
+        return (self._t1_collapsed,
+                {v: t.accumulators() for v, t in tables.items()})
+
     def t1_rows_collapsed(self) -> int:
+        if self._stats_h is not None:
+            return int(self._stats_h[0])
+        # a pass with no final fetch (a stream) pays for its own
         return int(device_get(self._t1_collapsed, "stats-t1"))
+
+    def cache_accumulators(self) -> Optional[Dict[int, Dict[str, Any]]]:
+        """Host values of the tier-2 accumulators if they rode the pass's
+        last fetch, else None (``CacheManager.stats`` then fetches)."""
+        return None if self._stats_h is None else self._stats_h[1]
 
     # -- the interpreter -----------------------------------------------
     def _iter_emitted(self, stream: bool = False
@@ -555,7 +591,8 @@ class ScheduleExecutor:
         while pc < end:
             op = ops[pc]
             if op.kind == EXPAND:
-                chunks = self._op_expand(chunks, op)
+                with TraceAnnotation("clftj.op.expand"):
+                    chunks = self._op_expand(chunks, op)
                 pc += 1
             elif op.kind == ENTER_CHILD:
                 fold_pc = self._bracket[pc]
@@ -568,10 +605,12 @@ class ScheduleExecutor:
                 # subtree results are inserted into tier 2 before chunk
                 # i+1 probes (cross-morsel reuse within one query)
                 for F in chunks:
-                    frame, R = self._enter_one(F, op)
+                    with TraceAnnotation("clftj.op.enter"):
+                        frame, R = self._enter_one(F, op)
                     exits = yield from self._exec([R], pc + 1, fold_pc,
                                                   depth + 1, forward)
-                    parts = self._fold_one(frame, exits, ops[fold_pc])
+                    with TraceAnnotation("clftj.op.fold"):
+                        parts = self._fold_one(frame, exits, ops[fold_pc])
                     if forward and depth == 0:
                         # interior-span streaming (DESIGN.md §2.10):
                         # this morsel's continuations run the suffix now
@@ -585,24 +624,34 @@ class ScheduleExecutor:
                 chunks = self._admit(conts, "fold-admit")
                 pc = fold_pc + 1
             else:  # EMIT
-                self.op_runs["emit"] += 1
-                if self.mode == "count":
-                    for F in chunks:
-                        self._total = self._total + jnp.sum(
-                            jnp.where(F.valid, F.factor, 0))
-                elif chunks:
-                    # pack valid rows to the front (registry-dispatched
-                    # EMIT kernel) and retain only (packed, k) — holding
-                    # whole Frontiers until the fetch would keep factor/
-                    # orig/lo/hi alive for every result chunk
-                    efn = self.engine._emit_fn()
-                    path = getattr(self.engine, "emit_path", "xla")
-                    self.emit_path_runs[path] = (
-                        self.emit_path_runs.get(path, 0) + len(chunks))
-                    self.emitted_blocks += len(chunks)
-                    yield [efn(F.assign, F.valid) for F in chunks]
+                with TraceAnnotation("clftj.op.emit"):
+                    pairs = self._op_emit(chunks)
+                if pairs:  # spans close before a yield
+                    yield pairs
                 pc += 1
         return chunks
+
+    # -- EMIT ----------------------------------------------------------
+    def _op_emit(self, chunks) -> Optional[List[Tuple[Any, Any]]]:
+        """Count mode: add the chunks' factors to the total.  Evaluate
+        mode: pack valid rows to the front (registry-dispatched EMIT
+        kernel) and return only the ``(packed, k)`` pairs — holding whole
+        Frontiers until the fetch would keep factor/orig/lo/hi alive for
+        every result chunk."""
+        self.op_runs["emit"] += 1
+        if self.mode == "count":
+            for F in chunks:
+                self._total = self._total + jnp.sum(
+                    jnp.where(F.valid, F.factor, 0))
+            return None
+        if not chunks:
+            return None
+        efn = self.engine._emit_fn()
+        path = getattr(self.engine, "emit_path", "xla")
+        self.emit_path_runs[path] = (
+            self.emit_path_runs.get(path, 0) + len(chunks))
+        self.emitted_blocks += len(chunks)
+        return [efn(F.assign, F.valid) for F in chunks]
 
     # -- EXPAND --------------------------------------------------------
     def _op_expand(self, chunks, op: Op):
@@ -618,13 +667,16 @@ class ScheduleExecutor:
             (jnp.stack([F.lo[:, g_ai] for F in chunks]),
              jnp.stack([F.hi[:, g_ai] for F in chunks]),
              jnp.stack([F.valid for F in chunks])), "expand-plan")
+        self.expand_rows_in += int(va_h.sum())
         to_run: List[Any] = []
         oversized: List[Tuple[Any, np.ndarray]] = []
         for i, F in enumerate(chunks):
             r0 = np.searchsorted(rs, lo_h[i], side="left")
             r1 = np.searchsorted(rs, hi_h[i], side="left")
             counts = np.where(va_h[i], r1 - r0, 0).astype(np.int64)
-            if int(counts.sum()) <= cap:
+            n_pairs = int(counts.sum())
+            self.expand_candidates += n_pairs
+            if n_pairs <= cap:
                 to_run.append(F)
             else:
                 oversized.append((F, counts))
@@ -649,7 +701,11 @@ class ScheduleExecutor:
                 return kept
             self.expand_path_runs[path] = (
                 self.expand_path_runs.get(path, 0) + len(batch))
-            kept[-1:] = self._admit(kept[-1:] + batch, "expand-admit")
+            carried = kept[-1:]
+            kept[-1:], rows = self._admit_rows(carried + batch,
+                                               "expand-admit")
+            # the carried chunk's rows were counted with its own batch
+            self.expand_rows_out += int(rows[len(carried):].sum())
 
     # -- ENTER_CHILD (one parent chunk) --------------------------------
     def _enter_one(self, F, op: Op) -> Tuple[_Frame, Any]:
@@ -919,28 +975,34 @@ class ScheduleExecutor:
         chunk boundaries: they decide which tier-2 probes a payload insert
         precedes, hence the order of replayed and spliced rows, and a
         streamed pass must emit the rows of a one-shot pass in order."""
+        return self._admit_rows(out, label)[0]
+
+    def _admit_rows(self, out, label: str) -> Tuple[List[Any], np.ndarray]:
+        """:meth:`_admit`, also returning the valid rows of each chunk of
+        ``out`` (read from the same fetch)."""
         if not out:
-            return []
+            return [], np.zeros(0, np.int64)
         C = self.engine.capacity
         coalesce = self.mode == "count"
-        stats = np.asarray(device_get(
-            jnp.stack([_chunk_stats(F) for F in out]), label))
-        kept: List[Any] = []
-        acc, acc_n, acc_hi = None, 0, 0
-        for F, (n, lo, hi) in zip(out, stats.tolist()):
-            if n == 0:
-                continue
-            if (coalesce and acc is not None and acc_n + n <= C
-                    and acc_hi <= lo):
-                acc = _merge_chunks(acc, F)
-                acc_n, acc_hi = acc_n + n, hi
-                continue
+        with TraceAnnotation("clftj.admit"):
+            stats = np.asarray(device_get(
+                jnp.stack([_chunk_stats(F) for F in out]), label))
+            kept: List[Any] = []
+            acc, acc_n, acc_hi = None, 0, 0
+            for F, (n, lo, hi) in zip(out, stats.tolist()):
+                if n == 0:
+                    continue
+                if (coalesce and acc is not None and acc_n + n <= C
+                        and acc_hi <= lo):
+                    acc = _merge_chunks(acc, F)
+                    acc_n, acc_hi = acc_n + n, hi
+                    continue
+                if acc is not None:
+                    kept.append(acc)
+                acc, acc_n, acc_hi = F, n, hi
             if acc is not None:
                 kept.append(acc)
-            acc, acc_n, acc_hi = F, n, hi
-        if acc is not None:
-            kept.append(acc)
-        return kept
+        return kept, stats[:, 0]
 
 
 def _pack_parent_morsels(pcnt: np.ndarray, cap: int) -> List[np.ndarray]:

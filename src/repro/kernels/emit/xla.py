@@ -19,11 +19,14 @@ __all__ = ["build"]
 
 def build():
     """EMIT pack under the registry contract (module docstring): the
-    always-available XLA composition (valid-first order + gather)."""
+    always-available XLA composition (valid-first order + gather),
+    compiled to a module named ``jit_emit_step`` with its work under the
+    named scope ``pack``."""
 
     @jax.jit
-    def fn(assign, valid):
-        perm, k = valid_first(valid)
-        return assign[perm], k
+    def emit_step(assign, valid):
+        with jax.named_scope("pack"):
+            perm, k = valid_first(valid)
+            return assign[perm], k
 
-    return fn
+    return emit_step

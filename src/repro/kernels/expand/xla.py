@@ -59,45 +59,55 @@ def compact(F):
 def expand_step(F, g_col, g_rs, other_cols, *, d: int, g_ai: int,
                 other_ais: Tuple[int, ...], n_rows_g: int, impl: str):
     """One frontier expansion (module-level so the jit cache is shared by
-    every engine instance with the same query structure / array shapes)."""
+    every engine instance with the same query structure / array shapes).
+
+    The three phases carry named scopes, ``layout`` (guard runs, slot
+    offsets, slot-to-row search, row gathers), ``verify`` (the other
+    atoms' bounded searches) and ``compact``, so a profiler trace splits
+    the module's device time by phase; scopes change HLO metadata only."""
     C = F.assign.shape[0]
     nruns = g_rs.shape[0]
-    r0 = jnp.searchsorted(g_rs, F.lo[:, g_ai], side="left")
-    r1 = jnp.searchsorted(g_rs, F.hi[:, g_ai], side="left")
-    counts = jnp.where(F.valid, r1 - r0, 0).astype(jnp.int32)
-    offsets = jnp.cumsum(counts) - counts               # exclusive
-    needed = offsets[-1] + counts[-1]
-    slot = jnp.arange(C, dtype=jnp.int32)
-    src = jnp.searchsorted(offsets, slot, side="right") - 1
-    src = jnp.clip(src, 0, C - 1)
-    delta = slot - offsets[src]
-    ok = (slot < needed) & (delta < counts[src])
-    if nruns:
-        k = jnp.clip(r0[src] + delta, 0, nruns - 1)
-        pos = g_rs[k]
-        value = g_col[jnp.clip(pos, 0, max(n_rows_g - 1, 0))]
-        run_end = jnp.where(k + 1 < nruns,
-                            g_rs[jnp.clip(k + 1, 0, nruns - 1)],
-                            n_rows_g).astype(jnp.int32)
-    else:
-        k = jnp.zeros_like(slot)
-        pos = jnp.zeros_like(slot)
-        value = jnp.zeros_like(slot)
-        run_end = jnp.zeros_like(slot)
-        ok = ok & False
-    lo2 = F.lo[src].at[:, g_ai].set(pos)
-    hi2 = F.hi[src].at[:, g_ai].set(run_end)
-    for ai, col in zip(other_ais, other_cols):
-        s = lower_bound(col, value, F.lo[src, ai], F.hi[src, ai], impl=impl)
-        e = upper_bound(col, value, s, F.hi[src, ai], impl=impl)
-        ok = ok & (s < e)
-        lo2 = lo2.at[:, ai].set(s.astype(jnp.int32))
-        hi2 = hi2.at[:, ai].set(e.astype(jnp.int32))
-    assign2 = F.assign[src].at[:, d].set(value.astype(jnp.int32))
-    out = F._replace(assign=assign2, factor=F.factor[src], valid=ok,
-                     orig=F.orig[src], lo=lo2.astype(jnp.int32),
+    with jax.named_scope("layout"):
+        r0 = jnp.searchsorted(g_rs, F.lo[:, g_ai], side="left")
+        r1 = jnp.searchsorted(g_rs, F.hi[:, g_ai], side="left")
+        counts = jnp.where(F.valid, r1 - r0, 0).astype(jnp.int32)
+        offsets = jnp.cumsum(counts) - counts               # exclusive
+        needed = offsets[-1] + counts[-1]
+        slot = jnp.arange(C, dtype=jnp.int32)
+        src = jnp.searchsorted(offsets, slot, side="right") - 1
+        src = jnp.clip(src, 0, C - 1)
+        delta = slot - offsets[src]
+        ok = (slot < needed) & (delta < counts[src])
+        if nruns:
+            k = jnp.clip(r0[src] + delta, 0, nruns - 1)
+            pos = g_rs[k]
+            value = g_col[jnp.clip(pos, 0, max(n_rows_g - 1, 0))]
+            run_end = jnp.where(k + 1 < nruns,
+                                g_rs[jnp.clip(k + 1, 0, nruns - 1)],
+                                n_rows_g).astype(jnp.int32)
+        else:
+            k = jnp.zeros_like(slot)
+            pos = jnp.zeros_like(slot)
+            value = jnp.zeros_like(slot)
+            run_end = jnp.zeros_like(slot)
+            ok = ok & False
+        lo2 = F.lo[src].at[:, g_ai].set(pos)
+        hi2 = F.hi[src].at[:, g_ai].set(run_end)
+        assign2 = F.assign[src].at[:, d].set(value.astype(jnp.int32))
+        factor2, orig2 = F.factor[src], F.orig[src]
+    with jax.named_scope("verify"):
+        for ai, col in zip(other_ais, other_cols):
+            s = lower_bound(col, value, F.lo[src, ai], F.hi[src, ai],
+                            impl=impl)
+            e = upper_bound(col, value, s, F.hi[src, ai], impl=impl)
+            ok = ok & (s < e)
+            lo2 = lo2.at[:, ai].set(s.astype(jnp.int32))
+            hi2 = hi2.at[:, ai].set(e.astype(jnp.int32))
+    out = F._replace(assign=assign2, factor=factor2, valid=ok,
+                     orig=orig2, lo=lo2.astype(jnp.int32),
                      hi=hi2.astype(jnp.int32))
-    return compact(out), needed
+    with jax.named_scope("compact"):
+        return compact(out), needed
 
 
 def build(*, d: int, g_ai: int, other_ais: Tuple[int, ...], n_rows_g: int,

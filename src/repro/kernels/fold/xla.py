@@ -153,42 +153,49 @@ def build(*, d0: int, d1: int, with_replay: bool, with_splice: bool,
     """FOLD step under the registry contract (module docstring): the
     always-available XLA op-chain composition.  ``sorted_exits=False``
     accepts exit chunks that break the sorted-exits invariant (the static
-    executor's folds over merged continuations)."""
+    executor's folds over merged continuations).  The step compiles to a
+    module named ``jit_fold_step``, its parts under the named scopes
+    ``replay``, ``splice`` and ``merge``."""
     if not (with_replay or with_splice):
         raise ValueError("FOLD build needs at least one of replay/splice")
 
     if with_replay and with_splice:
         @jax.jit
-        def fn(P, active, rep_of_row, E, hit, poff, plen, slab):
+        def fold_step(P, active, rep_of_row, E, hit, poff, plen, slab):
             C = P.valid.shape[0]
-            cont, needed = replay_step(P, active, rep_of_row, E,
-                                       d0=d0, d1=d1,
-                                       sorted_exits=sorted_exits)
+            with jax.named_scope("replay"):
+                cont, needed = replay_step(P, active, rep_of_row, E,
+                                           d0=d0, d1=d1,
+                                           sorted_exits=sorted_exits)
             # splice the payload hits, then append after the replay rows —
             # identical row order to the fused kernel's two-region layout
-            spl = splice_step(P, hit, poff, plen, slab, d0=d0, d1=d1)
-            n_spl = jnp.sum(jnp.where(hit, plen, 0).astype(jnp.int64))
-            merged, _ = merge_compact(cont, spl)
+            with jax.named_scope("splice"):
+                spl = splice_step(P, hit, poff, plen, slab, d0=d0, d1=d1)
+                n_spl = jnp.sum(jnp.where(hit, plen, 0).astype(jnp.int64))
+            with jax.named_scope("merge"):
+                merged, _ = merge_compact(cont, spl)
             return merged, _stats(C, needed, n_spl)
 
-        return fn
+        return fold_step
 
     if with_replay:
         @jax.jit
-        def fn(P, active, rep_of_row, E):
+        def fold_step(P, active, rep_of_row, E):
             C = P.valid.shape[0]
-            cont, needed = replay_step(P, active, rep_of_row, E,
-                                       d0=d0, d1=d1,
-                                       sorted_exits=sorted_exits)
+            with jax.named_scope("replay"):
+                cont, needed = replay_step(P, active, rep_of_row, E,
+                                           d0=d0, d1=d1,
+                                           sorted_exits=sorted_exits)
             return cont, _stats(C, needed, 0)
 
-        return fn
+        return fold_step
 
     @jax.jit
-    def fn(P, hit, poff, plen, slab):
+    def fold_step(P, hit, poff, plen, slab):
         C = P.valid.shape[0]
-        spl = splice_step(P, hit, poff, plen, slab, d0=d0, d1=d1)
-        n_spl = jnp.sum(jnp.where(hit, plen, 0).astype(jnp.int64))
+        with jax.named_scope("splice"):
+            spl = splice_step(P, hit, poff, plen, slab, d0=d0, d1=d1)
+            n_spl = jnp.sum(jnp.where(hit, plen, 0).astype(jnp.int64))
         return spl, _stats(C, 0, n_spl)
 
-    return fn
+    return fold_step

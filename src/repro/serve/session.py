@@ -17,7 +17,9 @@ worker's syncs land in it) and a
 :class:`~repro.core.engine.CompileClock` wrap each execution, engine
 counters are reported as per-query *deltas* (the plan-cached engine
 accumulates across queries), and ``plan_cache_hit`` rides the counters
-into :class:`~repro.core.engine.Result`.
+into :class:`~repro.core.engine.Result`.  In a profiler trace the
+worker's plan-cache lookup is the host span ``clftj.serve.plan`` and the
+engine call ``clftj.serve.execute``.
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ from collections import deque
 from typing import Dict, Iterator, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.cq import CQ
 from ..core.db import Database
@@ -271,8 +274,9 @@ class JoinServer:
         error: Optional[BaseException] = None
         try:
             with self._exec_lock:
-                entry, hit, pos = self.plan_cache.lookup(
-                    sess.query, sess.td_arg, sess.order_arg)
+                with TraceAnnotation("clftj.serve.plan"):
+                    entry, hit, pos = self.plan_cache.lookup(
+                        sess.query, sess.td_arg, sess.order_arg)
                 inv = {f"v{i}": v for v, i in pos.items()}
                 sess.order = tuple(inv[c] for c in entry.order)
                 sess.plan_cache_hit = hit
@@ -283,7 +287,7 @@ class JoinServer:
                 tuples = None
                 sc = SyncCounter()
                 cc = CompileClock()
-                with cc, sc:
+                with cc, sc, TraceAnnotation("clftj.serve.execute"):
                     if sess.mode == "count":
                         n = eng.count()
                     elif sess.mode == "evaluate":

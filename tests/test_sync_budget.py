@@ -10,7 +10,7 @@ syncs per EXPAND run (planning fetch, split fetch, admission), 1 per FOLD
 run (replay planning in evaluate mode), 1 per span close (continuation
 admission), plus emission and stats finalization.  If someone
 reintroduces a per-chunk ``bool(...)`` these fail with the offending
-label in ``events``."""
+label in ``label_counts``."""
 import numpy as np
 import pytest
 
@@ -40,7 +40,7 @@ def test_triangle_stays_under_sync_budget(db):
     with SyncCounter() as sc:
         got = eng.count()
     assert got == want
-    assert sc.count <= _budget(eng), sc.events
+    assert sc.count <= _budget(eng), sc.label_counts
 
 
 @pytest.mark.parametrize("cap", [1 << 13, 1 << 9, 1 << 7])
@@ -53,7 +53,7 @@ def test_sync_budget_scales_with_op_runs_not_chunks(db, cap):
     eng = JaxCachedTrieJoin(q, td, order, db, capacity=cap)
     with SyncCounter() as sc:
         eng.count()
-    assert sc.count <= _budget(eng), (cap, sc.events)
+    assert sc.count <= _budget(eng), (cap, sc.label_counts)
 
 
 @pytest.mark.parametrize("cap", [1 << 11, 1 << 7])
@@ -70,7 +70,7 @@ def test_multibag_td_sync_budget(db, cap):
     with SyncCounter() as sc:
         got = eng.count()
     assert got == want
-    assert sc.count <= _budget(eng), sc.events
+    assert sc.count <= _budget(eng), sc.label_counts
 
 
 def test_evaluate_mode_sync_budget(db):
@@ -83,7 +83,7 @@ def test_evaluate_mode_sync_budget(db):
         blocks = list(eng.evaluate())
     n = sum(b.shape[0] for b in blocks)
     assert n == lftj_count(q, order, db)
-    assert sc.count <= _budget(eng), sc.events
+    assert sc.count <= _budget(eng), sc.label_counts
 
 
 @pytest.mark.tier1
@@ -104,7 +104,7 @@ def test_evaluate_payload_sync_budget(db):
     assert n1 == n2 == lftj_count(q, order, db)
     assert eng.stats["tier2_replay_hits"] > 0, "payload path not exercised"
     r = eng.last_executor.op_runs
-    assert sc.count <= _budget(eng), sc.events
+    assert sc.count <= _budget(eng), sc.label_counts
     # payload fetches are batched per fold op, never per hit
     assert sc.label_counts["replay-plan"] <= r["fold"], sc.label_counts
 
@@ -129,7 +129,7 @@ def test_evaluate_stream_sync_budget(db):
     assert eng.stats["tier2_replay_hits"] > 0, "payload path not exercised"
     r = eng.last_executor.op_runs
     # blocking budget unchanged — streaming adds no blocking syncs at all
-    assert sc.count <= _budget(eng), sc.events
+    assert sc.count <= _budget(eng), sc.label_counts
     assert sc.label_counts["emit-rows"] == 0, "one-shot drain in stream mode"
     # every emitted block left through the async queue, labeled as such;
     # interior-span streaming additionally issues replay plans async
@@ -146,4 +146,4 @@ def test_vanilla_lftj_sync_budget(db):
     eng = JaxTrieJoin(q, order, db, capacity=1 << 12)
     with SyncCounter() as sc:
         eng.count()
-    assert sc.count <= _budget(eng, stats_slack=2), sc.events
+    assert sc.count <= _budget(eng, stats_slack=2), sc.label_counts
