@@ -20,15 +20,21 @@ def overlap(a, b):
     return out
 
 
-def read(run):
-    t = run.trace
-    if t is None or not run.requests:
-        return None
+def op_spans_and_idle(t):
+    """The union of the op spans in the window, and device 0's idle gaps
+    there."""
     lo, hi = t.window
     ops = trace.union(trace.clip(
         ((a, b) for name, a, b in t.spans if name.startswith(OP_SPANS)),
         lo, hi))
+    return ops, trace.gaps(t.devices[0].busy, lo, hi)
+
+
+def read(run):
+    t = run.trace
+    if t is None or not run.requests:
+        return None
+    ops, idle = op_spans_and_idle(t)
     if not ops:
         return None
-    idle = trace.gaps(t.devices[0].busy, lo, hi)
     return 1e-6 * overlap(ops, idle) / len(run.requests)

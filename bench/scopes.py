@@ -11,7 +11,10 @@ function, first named scope).  Ops nest (a ``while`` holds its body's
 ops), so each instant is credited once, to the innermost op running then;
 a module's scope seconds never exceed its module seconds.
 
-    summary, scopes = load(trace_dir)   # scopes[("expand_step", "verify")]
+    data = open(xplane_path, "rb").read()
+    pd = ProfileData.from_serialized_xspace(data)
+    secs = scope_s(pd, op_scopes(data), trace.reduce(pd).window)
+    secs[("expand_step", "verify")]
 
 ``bench/trace.py`` does not call this yet: the per-layer metrics read a
 ``trace.Summary``, which holds no op events, so a metric of scope time
@@ -19,8 +22,6 @@ a module's scope seconds never exceed its module seconds.
 """
 from __future__ import annotations
 
-import glob
-import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -165,20 +166,3 @@ def scope_s(pd, scopes: Dict[str, Sequence[Optional[Key]]],
             if k is not None and c:
                 out[k] = out.get(k, 0.0) + trace.length(c) * 1e-9
     return out
-
-
-def load(trace_dir: str) -> Tuple[trace.Summary, Dict[Key, float]]:
-    """The :class:`trace.Summary` of the one ``.xplane.pb`` under
-    ``trace_dir`` and its device seconds per scope in the same window."""
-    from jax.profiler import ProfileData
-
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if len(paths) != 1:
-        raise ValueError(f"expected one .xplane.pb under {trace_dir}, "
-                         f"found {len(paths)}")
-    with open(paths[0], "rb") as f:
-        data = f.read()
-    pd = ProfileData.from_serialized_xspace(data)
-    summary = trace.reduce(pd)
-    return summary, scope_s(pd, op_scopes(data), summary.window)

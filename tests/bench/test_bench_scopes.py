@@ -93,16 +93,25 @@ def test_scope_seconds_stay_within_module_seconds(hand_scopes):
 
 def test_readers_give_the_hand_computed_values(hand_scopes):
     reqs = [Request(0, 1, 0, counters={"expand_candidates": 100,
+                                       "expand_rows_in": 20,
+                                       "expand_rows_out": 30,
                                        "expand_calls_xla": 2}),
             Request(1, 2, 0, counters={"expand_candidates": 150,
+                                       "expand_rows_in": 30,
+                                       "expand_rows_out": 20,
                                        "expand_calls_xla": 2,
                                        "expand_calls_pallas": 1})]
     view = _view(hand_scopes[0], reqs)
     assert _read("expand_fill", view) == pytest.approx(
         100 * 250 / (5 * 64))
+    assert _read("expand_pass_rate", view) == pytest.approx(100 * 50 / 250)
+    assert _read("expand_fanout", view) == pytest.approx(250 / 50)
     # device 0 idles over [0, 1], [9, 10], [12, 12.5] and [13, 19]; the op
     # spans cover [0.5, 12.2] and [14, 16]: 0.5 + 1 + 0.2 + 2 = 3.7 us
     assert _read("interp_idle_ms", view) == pytest.approx(3.7e-3 / 2)
+    # per request (clftj.serve.execute): [0.2, 10] idles 0.5 + 1 us inside
+    # op spans, [10, 18] 0.2 + 2 us, [18, 19.5] none: median 1.5 us
+    assert _read("interp_idle_ms_median", view) == pytest.approx(1.5e-3)
 
 
 def test_readers_find_nothing_without_the_program_s_instrumentation():
@@ -110,5 +119,6 @@ def test_readers_find_nothing_without_the_program_s_instrumentation():
     assert got == {}
     view = _view(summary, [Request(0, 1, 7,
                                    counters={"expand_calls_xla": 3})])
-    for name in ("expand_fill", "interp_idle_ms"):
+    for name in ("expand_fill", "expand_pass_rate", "expand_fanout",
+                 "interp_idle_ms", "interp_idle_ms_median"):
         assert _read(name, view) is None, name
