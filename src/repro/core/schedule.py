@@ -268,8 +268,8 @@ def _merge_chunks(A, B):
     caller guarantees they fit); either may have holes in ``valid``."""
     C = A.valid.shape[0]
     cat = type(A)(*(jnp.concatenate([a, b]) for a, b in zip(A, B)))
-    perm, k = valid_first(cat.valid)
-    out = type(A)(*(x[perm[:C]] for x in cat))
+    perm, k = valid_first(cat.valid, C)
+    out = type(A)(*(x[perm] for x in cat))
     return out._replace(valid=jnp.arange(C) < k)
 
 

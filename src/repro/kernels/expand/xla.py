@@ -8,11 +8,12 @@ validated against the plain-numpy oracle in ``ref.py``:
 
 * enumerate each valid row's guard candidate runs (searchsorted over the
   run-start array), lay the (row, candidate) pairs out over output slots
-  via cumsum + searchsorted;
+  (cumsum of the counts, inverted by counting: :func:`count_le`);
 * verify each candidate's membership in every other participating atom
   with bounded binary search (two per atom), narrowing that atom's
   [lo, hi) trie window;
-* compact surviving rows to the front of the chunk (stable partition).
+* compact surviving rows to the front of the chunk (stable partition,
+  the survivor scan inverted the same way).
 
 XLA materializes ~6 intermediate arrays per participating atom here — the
 memory-traffic motivation for the fused kernel.  The functions are generic
@@ -28,20 +29,34 @@ import jax.numpy as jnp
 
 from ..registry import lower_bound, upper_bound
 
-__all__ = ["build", "expand_step", "compact", "valid_first"]
+__all__ = ["build", "expand_step", "compact", "count_le", "valid_first"]
 
 
-def valid_first(valid):
+def count_le(offsets, out: int):
+    """``#{i : offsets[i] <= j}`` for ``j`` in ``0..out-1``, for any
+    non-negative, non-decreasing int32 ``offsets``: the inverse of a
+    prefix sum, i.e. ``searchsorted(offsets, j, side="right")``.
+
+    One scatter-add of ones at ``min(offsets, out)`` (sorted, because
+    ``offsets`` is monotone) and one scan.  A binary search would be a
+    loop of ``log2(n)`` full-width gathers on a TPU."""
+    hist = jnp.zeros(out + 1, jnp.int32).at[jnp.minimum(offsets, out)].add(
+        1, indices_are_sorted=True)
+    return jnp.cumsum(hist)[:out]
+
+
+def valid_first(valid, out: int | None = None):
     """``(perm, k)``: the stable valid-first order of a chunk without a
-    sort.  Slot j takes the (j+1)-th valid row, the first index whose
-    inclusive valid count reaches j+1; ``k`` is the valid count and slots
-    from ``k`` on point at arbitrary rows.  (A sort of 2^16 keys takes
-    tens of seconds to compile for TPU; this takes about one.)"""
-    C = valid.shape[0]
+    sort or a search.  Slot j takes the (j+1)-th valid row, the first
+    index whose inclusive valid count reaches j+1, i.e. the number of rows
+    whose count is at most j; ``k`` is the valid count and slots from
+    ``k`` on point at arbitrary rows (clipped into the chunk).  ``out``
+    (default: every row) is how many slots to build.  (A sort of 2^16
+    keys takes tens of seconds to compile for TPU; this takes about one.)"""
+    n = valid.shape[0]
     csum = jnp.cumsum(valid.astype(jnp.int32))
-    perm = jnp.searchsorted(csum, jnp.arange(1, C + 1, dtype=jnp.int32),
-                            side="left")
-    return jnp.clip(perm, 0, C - 1).astype(jnp.int32), csum[-1]
+    perm = count_le(csum, n if out is None else out)
+    return jnp.clip(perm, 0, n - 1), csum[-1]
 
 
 @jax.jit
@@ -62,7 +77,7 @@ def expand_step(F, g_col, g_rs, other_cols, *, d: int, g_ai: int,
     every engine instance with the same query structure / array shapes).
 
     The three phases carry named scopes, ``layout`` (guard runs, slot
-    offsets, slot-to-row search, row gathers), ``verify`` (the other
+    offsets, slot-to-row map, row gathers), ``verify`` (the other
     atoms' bounded searches) and ``compact``, so a profiler trace splits
     the module's device time by phase; scopes change HLO metadata only."""
     C = F.assign.shape[0]
@@ -74,8 +89,7 @@ def expand_step(F, g_col, g_rs, other_cols, *, d: int, g_ai: int,
         offsets = jnp.cumsum(counts) - counts               # exclusive
         needed = offsets[-1] + counts[-1]
         slot = jnp.arange(C, dtype=jnp.int32)
-        src = jnp.searchsorted(offsets, slot, side="right") - 1
-        src = jnp.clip(src, 0, C - 1)
+        src = jnp.clip(count_le(offsets, C) - 1, 0, C - 1)
         delta = slot - offsets[src]
         ok = (slot < needed) & (delta < counts[src])
         if nruns:

@@ -29,7 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..expand.xla import compact
+from ..expand.xla import compact, count_le
 
 __all__ = ["replay_step", "splice_step", "merge_compact", "build"]
 
@@ -57,13 +57,13 @@ def replay_step(P, active, rep_of_row, E, *, d0: int, d1: int,
     eorder = _exit_order(E, sorted_exits)
     estart = jnp.cumsum(ecnt) - ecnt
     # enumerate (parent, exit) pairs exactly like _expand_step enumerates
-    # (row, candidate) pairs: cumsum offsets + searchsorted
+    # (row, candidate) pairs: cumsum offsets, inverted by counting
     rep = jnp.clip(rep_of_row, 0, C - 1)
     pcnt = jnp.where(active, ecnt[rep], 0).astype(jnp.int32)
     offsets = jnp.cumsum(pcnt) - pcnt
     needed = offsets[-1] + pcnt[-1]
     slot = jnp.arange(C, dtype=jnp.int32)
-    src = jnp.clip(jnp.searchsorted(offsets, slot, side="right") - 1, 0, C - 1)
+    src = jnp.clip(count_le(offsets, C) - 1, 0, C - 1)
     delta = slot - offsets[src]
     ok = (slot < needed) & (delta < pcnt[src])
     eidx = eorder[jnp.clip(estart[rep[src]] + delta, 0, C - 1)]
@@ -106,7 +106,7 @@ def splice_step(P, mask, poff, plen, slab, *, d0: int, d1: int):
     offsets = jnp.cumsum(pcnt) - pcnt
     needed = offsets[-1] + pcnt[-1]
     slot = jnp.arange(C, dtype=jnp.int32)
-    src = jnp.clip(jnp.searchsorted(offsets, slot, side="right") - 1, 0, C - 1)
+    src = jnp.clip(count_le(offsets, C) - 1, 0, C - 1)
     delta = slot - offsets[src]
     ok = (slot < needed) & (delta < pcnt[src])
     sidx = jnp.where(ok, jnp.clip(poff[src] + delta, 0, R - 1), R)

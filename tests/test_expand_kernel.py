@@ -212,6 +212,68 @@ def test_block_q_config_snaps_to_divisor(cap, block_q):
 
 
 # ---------------------------------------------------------------------------
+# Prefix-sum inversion by counting (XLA chain)
+# ---------------------------------------------------------------------------
+
+def _inversion_counts(case, C, rng):
+    """Non-negative per-row counts; ``valid`` is ``counts > 0``."""
+    if case == "all_invalid":
+        return np.zeros(C, np.int32)
+    if case == "all_valid":
+        return np.ones(C, np.int32)
+    if case in ("single_first", "single_last"):
+        c = np.zeros(C, np.int32)
+        c[0 if case == "single_first" else -1] = 1
+        return c
+    if case.startswith("density_"):
+        valid = rng.random(C) < float(case.split("_")[1])
+        return np.where(valid, rng.integers(1, 4, C), 0).astype(np.int32)
+    if case == "counts_with_zeros":
+        return rng.integers(0, 3, C).astype(np.int32)
+    if case == "offsets_reach_C":
+        c = rng.integers(0, 5, C).astype(np.int32)
+        c[C // 2] = C        # some offsets land on C exactly, most past it
+        return c
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("C", [8, 64, 1024])
+@pytest.mark.parametrize("case", [
+    "all_invalid", "all_valid", "single_first", "single_last",
+    "density_0.05", "density_0.5", "density_0.95", "counts_with_zeros",
+    "offsets_reach_C"])
+def test_counting_inversion_matches_searchsorted(case, C):
+    """``count_le``, ``valid_first`` (every slot, past ``k`` too, and a
+    shorter output) and EXPAND's slot-to-row map equal their
+    ``jnp.searchsorted`` forms."""
+    counts = _inversion_counts(case, C, np.random.default_rng(C))
+    with jax.enable_x64(True):
+        valid = jnp.asarray(counts > 0)
+        csum = jnp.cumsum(valid.astype(jnp.int32))
+        want_perm = jnp.clip(jnp.searchsorted(
+            csum, jnp.arange(1, C + 1, dtype=jnp.int32), side="left"),
+            0, C - 1)
+        for out in (C, C // 2):
+            got = xla_mod.count_le(csum, out)
+            want = jnp.searchsorted(csum, jnp.arange(out, dtype=jnp.int32),
+                                    side="right")
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+            perm, k = xla_mod.valid_first(valid, out)
+            assert perm.dtype == jnp.int32 and perm.shape == (out,)
+            np.testing.assert_array_equal(np.asarray(perm),
+                                          np.asarray(want_perm[:out]))
+            assert int(k) == int(np.count_nonzero(counts))
+        c = jnp.asarray(counts)
+        offsets = jnp.cumsum(c) - c
+        slot = jnp.arange(C, dtype=jnp.int32)
+        want_src = jnp.clip(jnp.searchsorted(offsets, slot, side="right") - 1,
+                            0, C - 1)
+        got_src = jnp.clip(xla_mod.count_le(offsets, C) - 1, 0, C - 1)
+        np.testing.assert_array_equal(np.asarray(got_src),
+                                      np.asarray(want_src))
+
+
+# ---------------------------------------------------------------------------
 # Dispatch + autotune
 # ---------------------------------------------------------------------------
 

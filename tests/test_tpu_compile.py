@@ -4,7 +4,9 @@ The main path's XLA steps (EXPAND, merged FOLD, EMIT) must compile at
 the default ``frontier_capacity = 1 << 16`` with x64 on, over trie
 columns the size of ``chip_smoke.py``'s graph (1,768,149 edges), and a
 fused kernel that the TPU compiler refuses must make a forced
-``"pallas"`` raise instead of falling back.  The topology is described
+``"pallas"`` raise instead of falling back.  Prefix sums are inverted by
+counting, not by a binary search (a ``while`` loop of full-width
+gathers on the TPU).  The topology is described
 inside a fixture, so only the worker that runs this file loads the TPU
 compiler.
 """
@@ -15,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import schedule
 from repro.core.frontier import Frontier
 from repro.kernels import registry
 from repro.kernels.emit import FusedEmitConfig, xla as emit_xla
@@ -73,6 +76,35 @@ def test_xla_expand_compiles(one_chip):
         _frontier(one_chip), col, col, (col,), d=2, g_ai=1, other_ais=(2,),
         n_rows_g=N_EDGES, impl="bsearch").compile()
     assert _has_no_sort(compiled)
+
+
+def _n_loops(compiled):
+    return compiled.as_text().count(" while(")
+
+
+@pytest.mark.parametrize("step", ["compact", "merge_chunks", "emit_pack",
+                                  "expand_1_other", "expand_2_others"])
+def test_prefix_sum_inversions_compile_without_loops(one_chip, step):
+    """Compaction, chunk merging and the EMIT pack hold no loop; EXPAND
+    holds only its searches by value: the guard's run starts (two) and
+    two membership searches per other atom."""
+    F = _frontier(one_chip)
+    col = _arr(one_chip, (N_EDGES,), jnp.int32)
+    if step == "compact":
+        lowered, loops = expand_xla.compact.lower(F), 0
+    elif step == "merge_chunks":
+        lowered, loops = schedule._merge_chunks.lower(F, F), 0
+    elif step == "emit_pack":
+        lowered, loops = jax.jit(emit_xla.build()).lower(
+            _arr(one_chip, (C, N_VARS), jnp.int32),
+            _arr(one_chip, (C,), jnp.bool_)), 0
+    else:
+        others = (1, 2) if step == "expand_2_others" else (2,)
+        lowered = expand_xla.expand_step.lower(
+            F, col, col, (col,) * len(others), d=2, g_ai=0,
+            other_ais=others, n_rows_g=N_EDGES, impl="bsearch")
+        loops = 2 + 2 * len(others)
+    assert _n_loops(lowered.compile()) <= loops
 
 
 def test_xla_merged_fold_compiles(one_chip):
