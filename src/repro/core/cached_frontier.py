@@ -117,6 +117,7 @@ class JaxCachedTrieJoin(JaxTrieJoin):
                       "tier2_slab_rows": 0, "subtree_launches": 0,
                       "expand_rows_in": 0, "expand_candidates": 0,
                       "expand_rows_out": 0,
+                      "fold_rows_replayed": 0, "fold_rows_spliced": 0,
                       "expand_calls_pallas": 0, "expand_calls_xla": 0,
                       "fold_calls_pallas": 0, "fold_calls_xla": 0,
                       "emit_calls_pallas": 0, "emit_calls_xla": 0}
@@ -150,7 +151,8 @@ class JaxCachedTrieJoin(JaxTrieJoin):
         self.stats["tier2_slab_rows"] = agg.get("slab_rows", 0)
         self.stats["tier1_rows_collapsed"] += ex.t1_rows_collapsed()
         self.stats["subtree_launches"] += ex.subtree_launches
-        for k in ("expand_rows_in", "expand_candidates", "expand_rows_out"):
+        for k in ("expand_rows_in", "expand_candidates", "expand_rows_out",
+                  "fold_rows_replayed", "fold_rows_spliced"):
             self.stats[k] += getattr(ex, k)
         for path, runs in ex.expand_path_runs.items():
             self.stats[f"expand_calls_{path}"] = (

@@ -407,9 +407,11 @@ class ScheduleExecutor:
     is ``clftj.admit``), closed before any ``yield``.  The EXPAND
     counters (``expand_rows_in``, ``expand_candidates``,
     ``expand_rows_out``) are read from the planning and admission
-    fetches, and the device-side counters (tier-1 collapses, tier-2
-    accumulators) ride the pass's last fetch where it has one
-    (``emit-total``, ``emit-rows``): no counter adds a sync.
+    fetches, the FOLD counters (``fold_rows_replayed``,
+    ``fold_rows_spliced``) from the replay plan, and the device-side
+    counters (tier-1 collapses, tier-2 accumulators) ride the pass's last
+    fetch where it has one (``emit-total``, ``emit-rows``): no counter
+    adds a sync.
     """
 
     def __init__(self, engine, mode: str = "count"):
@@ -437,6 +439,11 @@ class ScheduleExecutor:
         self.expand_rows_in = 0
         self.expand_candidates = 0
         self.expand_rows_out = 0
+        # FOLD work (evaluate mode): rows its replay steps write from
+        # subtree exits and its splice steps from tier-2 slab payloads —
+        # summed from the replay plan the fold fetches anyway
+        self.fold_rows_replayed = 0
+        self.fold_rows_spliced = 0
         # op-execution counters: span interiors re-run once per parent
         # morsel, so the sync budget scales with these, never with the
         # number of chunks inside one op execution
@@ -829,6 +836,7 @@ class ScheduleExecutor:
                       evalid.astype(np.int64))
             ecnts.append(ecnt)
             pcnt = np.where(active_h, ecnt[np.clip(ror_h, 0, C - 1)], 0)
+            self.fold_rows_replayed += int(pcnt.sum())
             for mask in _pack_parent_morsels(pcnt, C):
                 cont, _stats = fold_replay(
                     fr.F, active_dev & jnp.asarray(mask), fr.rep_of_row, E)
@@ -848,6 +856,7 @@ class ScheduleExecutor:
                 spath = getattr(eng, "fold_paths", {}).get(
                     (d0, d1, False, True), "xla")
                 pcnt = np.where(hit_h, plen_h, 0).astype(np.int64)
+                self.fold_rows_spliced += int(pcnt.sum())
                 for mask in _pack_parent_morsels(pcnt, C):
                     spl, _stats = fold_splice(
                         fr.F, fr.hit & jnp.asarray(mask), fr.poff, fr.plen,

@@ -5,8 +5,10 @@ enumeration, counters that ride fetches already made, and the XLA steps'
 module names and named scopes."""
 import dataclasses
 import glob
+import json
 import os
 from collections import Counter
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -16,6 +18,7 @@ from repro.core import (CacheConfig, SyncCounter, bowtie_query, choose_plan,
 from repro.core.cached_frontier import JaxCachedTrieJoin
 from repro.core.db import graph_db
 
+ROOT = Path(__file__).resolve().parents[1]
 BOWTIE_ORDER = ("x2", "x3", "x1", "x4", "x5")
 # the syncs of the schedule's own ops in count mode: no counter's fetch
 OP_LABELS = {"expand-plan", "expand-split", "expand-admit", "fold-admit",
@@ -188,3 +191,87 @@ def test_xla_steps_lower_to_named_modules_with_scopes():
         assert f"module @{module} " in text
         for s in scopes:
             assert f"/{s}/" in text, (module, s)
+
+
+# the mutual 2-hop sessions' sync labels (blocking and async alike), as
+# the schedule made them before the FOLD counters: one root bag of three
+# EXPANDs, one fold, sixteen 2^10-row blocks
+_ROOT_BAG = {"expand-plan": 3, "expand-admit": 3, "fold-admit": 1}
+STREAM_LABELS_COLD = dict(_ROOT_BAG, **{
+    "replay-plan-async": 2, "emit-stream": 16, "cache-stats": 1,
+    "stats-t1": 1})
+STREAM_LABELS_WARM = dict(STREAM_LABELS_COLD, **{"replay-plan-async": 1})
+COUNT_LABELS = dict(_ROOT_BAG, **{"emit-total": 1})
+EVALUATE_LABELS = dict(_ROOT_BAG, **{"replay-plan": 1, "emit-rows": 1})
+
+
+def _mutual_2hop_sessions(db_edges):
+    """Serve the mutual 2-hop stream twice (cold, then warm), a count and
+    an evaluation at TPU_SERVE's settings cut small; each session's rows,
+    counters and sync labels."""
+    from repro.configs import paper_clftj
+    from repro.core import engine
+    from repro.core.cq import CQ, Atom
+
+    q = CQ(tuple(Atom("E", v) for v in (("x1", "x2"), ("x2", "x1"),
+                                         ("x2", "x3"), ("x3", "x2"))))
+    db = graph_db(db_edges)
+    td, order = choose_plan(q, db.stats())
+    cfg = dataclasses.replace(paper_clftj.TPU_SERVE,
+                              frontier_capacity=1 << 10, cache_slots=512,
+                              payload_rows=1 << 12)
+    server = engine.serve(db, cfg)
+    out = []
+    try:
+        for mode in ("stream", "stream", "count", "evaluate"):
+            sess = server.submit(q, mode, td, order)
+            blocks = list(sess.blocks()) if mode == "stream" else []
+            res = sess.result(300)
+            rows = (np.concatenate(blocks) if blocks else res.tuples)
+            out.append((mode, rows, res, sess))
+    finally:
+        server.close()
+    return q, out
+
+
+def test_stream_fold_counters_replay_cold_and_splice_warm():
+    """``fold_rows_replayed`` and ``fold_rows_spliced`` on a served mutual
+    2-hop stream: the cold one replays every row from subtree exits (the
+    root bag's rows fit one chunk, so no parent morsel probes after an
+    insert), the warm one splices every row from tier-2 payloads.  The
+    counters add no sync and no async issue: each mode's labels are the
+    ones the schedule made before they existed."""
+    from bench import graphs, reference
+
+    config = json.loads((ROOT / "bench" / "configs" / "gap-kron.json")
+                        .read_text())
+    graph = dict(config, scale=6)
+    nv = graphs.vertices(graph)
+    edges = graphs.build(graph, 2 ** 31 + 11)
+    assert len(edges) == 754 < 1 << 10
+    q, runs = _mutual_2hop_sessions(edges)
+    (_, cold_rows, cold, cold_s), (_, warm_rows, warm, warm_s), \
+        (_, _, count, count_s), (_, ev_rows, ev, ev_s) = runs
+    query = [[a.relation, *a.vars] for a in q.atoms]
+    want = reference.packed(
+        reference.join_rows(query, edges, nv, cold_s.order), nv)
+    assert len(want) == 15932
+    for rows in (cold_rows, warm_rows, ev_rows):
+        assert reference.row_gap(rows, want, nv) == 0
+    c, w = cold.counters, warm.counters
+    assert c["fold_calls_xla"] > 1 and w["fold_calls_xla"] > 1
+    assert (c["fold_rows_replayed"], c["fold_rows_spliced"]) == (15932, 0)
+    assert (w["fold_rows_replayed"], w["fold_rows_spliced"]) == (0, 15932)
+    assert c["tier2_replay_hits"] == 0 < w["tier2_replay_hits"]
+    assert ev.counters["fold_rows_spliced"] == 15932
+    assert count.count == 15932
+    assert (count.counters["fold_rows_replayed"],
+            count.counters["fold_rows_spliced"]) == (0, 0)
+    # the labels each mode made before the counters existed
+    assert dict(cold_s.sync.label_counts) == STREAM_LABELS_COLD
+    assert dict(warm_s.sync.label_counts) == STREAM_LABELS_WARM
+    assert dict(count_s.sync.label_counts) == COUNT_LABELS
+    assert dict(ev_s.sync.label_counts) == EVALUATE_LABELS
+    for s in (cold_s, warm_s, count_s, ev_s):
+        assert s.sync.count + s.sync.async_count == sum(
+            s.sync.label_counts.values())
