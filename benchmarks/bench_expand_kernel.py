@@ -67,7 +67,8 @@ def micro_sweep() -> None:
             F = xla_mod.build(impl="bsearch", **a0)(eng.initial_frontier())[0]
             n_valid = int(np.asarray(F.valid).sum())
             fns = {"xla": xla_mod.build(impl="bsearch", **a1),
-                   "pallas": fused_mod.build(**a1)}
+                   "pallas": fused_mod.build(**{k: v for k, v in a1.items()
+                                                if k != "g_rank"})}
             ops = {}
             for impl, fn in fns.items():
                 ops[impl] = registry.device_op_count(fn, F)

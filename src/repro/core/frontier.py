@@ -4,11 +4,12 @@ See DESIGN.md §2.  The depth-first RJoin recursion of the paper's Figure 1 is
 re-derived as breadth-first *frontier expansion*: a frontier is a fixed
 capacity matrix of partial assignments (+ per-atom trie ranges); expanding
 variable ``x_d`` enumerates, for every row, the distinct candidate values of a
-*guard* atom (via precomputed run-start arrays — the columnar trie) and
-verifies membership in every other participating atom with batched bounded
-binary search.  The expansion step itself is a kernel behind the dispatch
-registry (``kernels/registry.py`` → fused Pallas or the XLA op chain in
-``kernels/expand/``, per the ``expand_kernel`` knob; DESIGN.md §2.7).
+*guard* atom (via precomputed run-start arrays and run-rank tables — the
+columnar trie) and verifies membership in every other participating atom
+with batched bounded binary search.  The expansion step itself is a kernel
+behind the dispatch registry (``kernels/registry.py`` → fused Pallas or the
+XLA op chain in ``kernels/expand/``, per the ``expand_kernel`` knob;
+DESIGN.md §2.7).
 The frontier after level d contains
 exactly the depth-d partial assignments LFTJ would visit, so worst-case
 optimality is inherited.  The static chunk capacity bounds *device* memory
@@ -66,27 +67,32 @@ class Frontier(NamedTuple):
 
 @dataclass(frozen=True)
 class AtomLevel:
-    """Columnar trie level: value column + run-start index (CSR)."""
+    """Columnar trie level: value column, run-start index (CSR) and
+    run-rank table.  ``rank[p]`` is the number of runs that start before
+    row position ``p``, i.e. ``searchsorted(runstarts, p, "left")`` for
+    every ``p`` in ``0..N``: EXPAND finds a guard window's runs with one
+    gather from it instead of a binary search over ``runstarts``."""
 
     col: jnp.ndarray        # (N,) int32 — rows[:, level]
     runstarts: jnp.ndarray  # (R,) int32 — positions where rows[:, :level+1] changes
+    rank: jnp.ndarray       # (N+1,) int32 — #{runstarts < p} for p in 0..N
     col_np: np.ndarray
     runstarts_np: np.ndarray
+    rank_np: np.ndarray
 
 
 def _build_levels(rows: np.ndarray) -> List[AtomLevel]:
     n, k = rows.shape
     levels = []
     for l in range(k):
-        if n == 0:
-            rs = np.zeros(0, dtype=np.int32)
-        else:
-            prefix = rows[:, :l + 1]
-            change = np.ones(n, dtype=bool)
-            change[1:] = (prefix[1:] != prefix[:-1]).any(axis=1)
-            rs = np.flatnonzero(change).astype(np.int32)
+        prefix = rows[:, :l + 1]
+        change = np.ones(n, dtype=bool)
+        change[1:] = (prefix[1:] != prefix[:-1]).any(axis=1)
+        rs = np.flatnonzero(change).astype(np.int32)
+        rank = np.concatenate([[0], np.cumsum(change)]).astype(np.int32)
         col = rows[:, l].astype(np.int32)
-        levels.append(AtomLevel(jnp.asarray(col), jnp.asarray(rs), col, rs))
+        levels.append(AtomLevel(jnp.asarray(col), jnp.asarray(rs),
+                                jnp.asarray(rank), col, rs, rank))
     return levels
 
 
@@ -222,7 +228,7 @@ class JaxTrieJoin:
                        if k != gi)
         return dict(d=d, g_ai=g_ai,
                     other_ais=tuple(ai for ai, _ in others),
-                    g_col=g.col, g_rs=g.runstarts,
+                    g_col=g.col, g_rs=g.runstarts, g_rank=g.rank,
                     other_cols=tuple(self.levels[ai][lvl].col
                                      for ai, lvl in others),
                     n_rows_g=self.sizes[g_ai])

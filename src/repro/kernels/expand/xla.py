@@ -6,9 +6,10 @@ implementation shares one entry-point convention.  Semantics are the
 contract the fused Pallas kernel (``fused.py``) is held to, and both are
 validated against the plain-numpy oracle in ``ref.py``:
 
-* enumerate each valid row's guard candidate runs (searchsorted over the
-  run-start array), lay the (row, candidate) pairs out over output slots
-  (cumsum of the counts, inverted by counting: :func:`count_le`);
+* enumerate each valid row's guard candidate runs (one gather from the
+  guard level's run-rank table at each end of the row's window: no
+  search), lay the (row, candidate) pairs out over output slots (cumsum
+  of the counts, inverted by counting: :func:`count_le`);
 * verify each candidate's membership in every other participating atom
   with bounded binary search (two per atom), narrowing that atom's
   [lo, hi) trie window;
@@ -71,10 +72,16 @@ def compact(F):
 @functools.partial(
     jax.jit,
     static_argnames=("d", "g_ai", "other_ais", "n_rows_g", "impl"))
-def expand_step(F, g_col, g_rs, other_cols, *, d: int, g_ai: int,
+def expand_step(F, g_col, g_rs, g_rank, other_cols, *, d: int, g_ai: int,
                 other_ais: Tuple[int, ...], n_rows_g: int, impl: str):
     """One frontier expansion (module-level so the jit cache is shared by
     every engine instance with the same query structure / array shapes).
+
+    ``g_rank`` is the guard level's run-rank table (``AtomLevel.rank``,
+    ``n_rows_g + 1`` entries): a window ``[lo, hi)`` holds the guard runs
+    ``g_rank[lo] .. g_rank[hi] - 1``.  A valid row's window lies in
+    ``[0, n_rows_g]``; the clip only keeps invalid rows' gathers in
+    bounds.
 
     The three phases carry named scopes, ``layout`` (guard runs, slot
     offsets, slot-to-row map, row gathers), ``verify`` (the other
@@ -83,8 +90,8 @@ def expand_step(F, g_col, g_rs, other_cols, *, d: int, g_ai: int,
     C = F.assign.shape[0]
     nruns = g_rs.shape[0]
     with jax.named_scope("layout"):
-        r0 = jnp.searchsorted(g_rs, F.lo[:, g_ai], side="left")
-        r1 = jnp.searchsorted(g_rs, F.hi[:, g_ai], side="left")
+        r0 = g_rank[jnp.clip(F.lo[:, g_ai], 0, n_rows_g)]
+        r1 = g_rank[jnp.clip(F.hi[:, g_ai], 0, n_rows_g)]
         counts = jnp.where(F.valid, r1 - r0, 0).astype(jnp.int32)
         offsets = jnp.cumsum(counts) - counts               # exclusive
         needed = offsets[-1] + counts[-1]
@@ -125,11 +132,12 @@ def expand_step(F, g_col, g_rs, other_cols, *, d: int, g_ai: int,
 
 
 def build(*, d: int, g_ai: int, other_ais: Tuple[int, ...], n_rows_g: int,
-          impl: str, g_col, g_rs, other_cols):
+          impl: str, g_col, g_rs, g_rank, other_cols):
     """Close the per-depth arrays over :func:`expand_step` → fn(F)."""
 
     def fn(F):
-        return expand_step(F, g_col, g_rs, other_cols, d=d, g_ai=g_ai,
-                           other_ais=other_ais, n_rows_g=n_rows_g, impl=impl)
+        return expand_step(F, g_col, g_rs, g_rank, other_cols, d=d,
+                           g_ai=g_ai, other_ais=other_ais, n_rows_g=n_rows_g,
+                           impl=impl)
 
     return fn

@@ -565,7 +565,7 @@ def _maybe_writethrough() -> None:
 def expand_fn(spec: ExpandSpec, *, mode: str = "auto", impl: str = "bsearch",
               config=None, measure: Optional[bool] = None,
               d: int, g_ai: int, other_ais: Tuple[int, ...],
-              g_col: jnp.ndarray, g_rs: jnp.ndarray,
+              g_col: jnp.ndarray, g_rs: jnp.ndarray, g_rank: jnp.ndarray,
               other_cols: Tuple[jnp.ndarray, ...], n_rows_g: int,
               sizes: Optional[Sequence[int]] = None,
               ) -> Tuple[Callable, str]:
@@ -573,13 +573,15 @@ def expand_fn(spec: ExpandSpec, *, mode: str = "auto", impl: str = "bsearch",
     where ``fn(F) -> (F', needed)`` and ``chosen`` names the impl that
     will actually run.  ``impl`` is the bounded-search flavor used by the
     XLA chain; ``config`` is a :class:`~.expand.fused.FusedExpandConfig`
-    for the Pallas path."""
+    for the Pallas path.  ``g_rank``, the guard level's run-rank table,
+    goes to the XLA chain alone: the fused kernel searches ``g_rs``."""
     from .expand import fused as _fused, xla as _xla  # lazy: no import cycle
 
     def build_xla():
         return _xla.build(d=d, g_ai=g_ai, other_ais=other_ais,
                           n_rows_g=n_rows_g, impl=impl,
-                          g_col=g_col, g_rs=g_rs, other_cols=other_cols)
+                          g_col=g_col, g_rs=g_rs, g_rank=g_rank,
+                          other_cols=other_cols)
 
     def build_fused():
         return _fused.build(d=d, g_ai=g_ai, other_ais=other_ais,

@@ -25,10 +25,15 @@ def _db(seed=5, nv=10, ne=70):
     return graph_db(rng.integers(0, nv, size=(ne, 2)))
 
 
+def _fused_args(a):
+    """The fused kernel keeps its own guard search: no run-rank table."""
+    return {k: v for k, v in a.items() if k != "g_rank"}
+
+
 def _build_pair(eng, d, config=None):
     a = eng.expand_kernel_args(d)
     fx = xla_mod.build(impl="bsearch", **a)
-    fp = fused_mod.build(config=config, **a)
+    fp = fused_mod.build(config=config, **_fused_args(a))
     return fx, fp, a
 
 
@@ -274,6 +279,93 @@ def test_counting_inversion_matches_searchsorted(case, C):
 
 
 # ---------------------------------------------------------------------------
+# Guard run-rank table (XLA chain)
+# ---------------------------------------------------------------------------
+
+_RANK_RELATIONS = {
+    "empty": np.zeros((0, 2), np.int64),
+    "one_row": np.asarray([[3, 5]], np.int64),
+    "single_run": np.asarray([[4, 0], [4, 1], [4, 7], [4, 9]], np.int64),
+}
+
+
+def _rank_tables(case):
+    """Every trie level the case builds: a conformance-zoo query's tries
+    over each zoo database, or one hand-made relation's levels."""
+    from test_conformance import ZOO, SEED, zipf_graph_db
+    from repro.core.frontier import JaxTrieJoin, _build_levels
+    if case in _RANK_RELATIONS:
+        return _build_levels(_RANK_RELATIONS[case])
+    q = dict(ZOO)[case]
+    out = []
+    for db in (zipf_graph_db(12, 60, 1.1, seed=SEED + 1),
+               zipf_graph_db(18, 90, 0.9, seed=SEED + 2)):
+        _, order = choose_plan(q, db.stats())
+        eng = JaxTrieJoin(q, order, db, capacity=1 << 6)
+        out += [lvl for atom in eng.levels for lvl in atom]
+    return out
+
+
+@pytest.mark.parametrize("case", ["4-clique", "5-cycle", "bowtie",
+                                  "rand-acyclic-5", "rand-acyclic-6",
+                                  "rand-acyclic-7", *_RANK_RELATIONS])
+def test_run_rank_table_matches_searchsorted(case):
+    """``AtomLevel.rank[p]`` is ``searchsorted(runstarts, p, "left")`` for
+    every row position ``p`` in ``0..N``, on device and host alike."""
+    levels = _rank_tables(case)
+    assert levels
+    for lvl in levels:
+        n = lvl.col_np.shape[0]
+        want = np.searchsorted(lvl.runstarts_np, np.arange(n + 1),
+                               side="left")
+        assert lvl.rank_np.dtype == np.int32 and lvl.rank_np.shape == (n + 1,)
+        np.testing.assert_array_equal(lvl.rank_np, want)
+        np.testing.assert_array_equal(np.asarray(lvl.rank), want)
+        assert lvl.rank.dtype == jnp.int32
+
+
+@pytest.mark.parametrize("qname,q,cap", [("triangle", cycle_query(3), 4),
+                                         ("4-cycle", cycle_query(4), 8),
+                                         ("star-3", star_query(3), 4)])
+def test_expand_matches_oracle_on_split_pieces(qname, q, cap):
+    """Every depth, every morsel piece ``split_chunk_host`` makes: the XLA
+    chain equals the numpy oracle bit for bit on guard windows that end
+    at the trie's last row (``N``) and on windows cut mid-row at a run
+    start, where the run-rank table replaces the search."""
+    from test_conformance import SEED, zipf_graph_db
+    from repro.core.frontier import JaxTrieJoin
+    db = zipf_graph_db(12, 60, 1.1, seed=SEED + 1)
+    _, order = choose_plan(q, db.stats())
+    eng = JaxTrieJoin(q, order, db, capacity=cap)
+    ends_at_n = split_rows = pieces = 0
+    with jax.enable_x64(True):
+        chunks = [eng.initial_frontier()]
+        for d in range(eng.n):
+            a = eng.expand_kernel_args(d)
+            fn = xla_mod.build(impl="bsearch", **a)
+            g_ai, rs, n_rows_g = eng.expand_plan(d)
+            nxt = []
+            for F in chunks:
+                host = {k: np.asarray(v) for k, v in F._asdict().items()}
+                r0 = np.searchsorted(rs, host["lo"][:, g_ai], side="left")
+                r1 = np.searchsorted(rs, host["hi"][:, g_ai], side="left")
+                counts = np.where(host["valid"], r1 - r0, 0)
+                # a row of more than ``cap`` runs is cut at run starts
+                split_rows += int((counts > cap).sum())
+                for P in eng.split_chunk_host(host, d, counts):
+                    hi = np.asarray(P.hi)[np.asarray(P.valid), g_ai]
+                    ends_at_n += int((hi == n_rows_g).any())
+                    Fo, no = fn(P)
+                    assert _assert_oracle(P, a, Fo, no), (qname, d)
+                    pieces += 1
+                    if int(np.asarray(Fo.valid).sum()):
+                        nxt.append(Fo)
+            chunks = nxt[:8]
+    assert pieces > eng.n and ends_at_n and split_rows, (
+        pieces, ends_at_n, split_rows)
+
+
+# ---------------------------------------------------------------------------
 # Dispatch + autotune
 # ---------------------------------------------------------------------------
 
@@ -365,7 +457,7 @@ def test_autotune_measured_caches_choice():
     spec = _spec(eng, 0)
     builders = {
         "xla": lambda: xla_mod.build(impl="bsearch", **a),
-        "pallas": lambda: fused_mod.build(**a),
+        "pallas": lambda: fused_mod.build(**_fused_args(a)),
     }
     with jax.enable_x64(True):
         choice = registry.select_expand(spec, mode="auto", measure=True,

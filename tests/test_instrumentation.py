@@ -180,9 +180,10 @@ def test_xla_steps_lower_to_named_modules_with_scopes():
         low = fold.lower(F, F.valid, idx, F, F.valid, idx, idx, slab)
         emit = emit_xla.build().lower(F.assign, F.valid)
         col = jnp.arange(C, dtype=jnp.int32)
+        rank = jnp.arange(C + 1, dtype=jnp.int32)
         expand = expand_xla.expand_step.lower(
-            F, col, col, (col,), d=0, g_ai=0, other_ais=(1,), n_rows_g=C,
-            impl="bsearch")
+            F, col, col, rank, (col,), d=0, g_ai=0, other_ais=(1,),
+            n_rows_g=C, impl="bsearch")
     for lowered, module, scopes in (
             (low, "jit_fold_step", ("replay", "splice", "merge")),
             (emit, "jit_emit_step", ("pack",)),

@@ -5,8 +5,8 @@ the default ``frontier_capacity = 1 << 16`` with x64 on, over trie
 columns the size of ``chip_smoke.py``'s graph (1,768,149 edges), and a
 fused kernel that the TPU compiler refuses must make a forced
 ``"pallas"`` raise instead of falling back.  Prefix sums are inverted by
-counting, not by a binary search (a ``while`` loop of full-width
-gathers on the TPU).  The topology is described
+counting and guard runs are ranked by a table, not by a binary search (a
+``while`` loop of full-width gathers on the TPU).  The topology is described
 inside a fixture, so only the worker that runs this file loads the TPU
 compiler.
 """
@@ -72,9 +72,10 @@ def _has_no_sort(compiled):
 
 def test_xla_expand_compiles(one_chip):
     col = _arr(one_chip, (N_EDGES,), jnp.int32)
+    rank = _arr(one_chip, (N_EDGES + 1,), jnp.int32)
     compiled = expand_xla.expand_step.lower(
-        _frontier(one_chip), col, col, (col,), d=2, g_ai=1, other_ais=(2,),
-        n_rows_g=N_EDGES, impl="bsearch").compile()
+        _frontier(one_chip), col, col, rank, (col,), d=2, g_ai=1,
+        other_ais=(2,), n_rows_g=N_EDGES, impl="bsearch").compile()
     assert _has_no_sort(compiled)
 
 
@@ -86,10 +87,11 @@ def _n_loops(compiled):
                                   "expand_1_other", "expand_2_others"])
 def test_prefix_sum_inversions_compile_without_loops(one_chip, step):
     """Compaction, chunk merging and the EMIT pack hold no loop; EXPAND
-    holds only its searches by value: the guard's run starts (two) and
-    two membership searches per other atom."""
+    holds only its membership searches by value, two per other atom: the
+    guard's runs come from one gather each end in its run-rank table."""
     F = _frontier(one_chip)
     col = _arr(one_chip, (N_EDGES,), jnp.int32)
+    rank = _arr(one_chip, (N_EDGES + 1,), jnp.int32)
     if step == "compact":
         lowered, loops = expand_xla.compact.lower(F), 0
     elif step == "merge_chunks":
@@ -101,9 +103,9 @@ def test_prefix_sum_inversions_compile_without_loops(one_chip, step):
     else:
         others = (1, 2) if step == "expand_2_others" else (2,)
         lowered = expand_xla.expand_step.lower(
-            F, col, col, (col,) * len(others), d=2, g_ai=0,
+            F, col, col, rank, (col,) * len(others), d=2, g_ai=0,
             other_ais=others, n_rows_g=N_EDGES, impl="bsearch")
-        loops = 2 + 2 * len(others)
+        loops = 2 * len(others)
     assert _n_loops(lowered.compile()) <= loops
 
 
